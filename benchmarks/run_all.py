@@ -85,6 +85,10 @@ def main() -> None:
                     help="also run the printed-only benchmark suites")
     args = ap.parse_args()
 
+    from repro import compile_cache
+
+    compile_cache.enable()
+
     from benchmarks import (bench_async, bench_eval, bench_latency,
                             bench_online, bench_pipeline, bench_scale,
                             bench_serve, bench_trace)

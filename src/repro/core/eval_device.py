@@ -56,7 +56,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import eval as host_eval
 from repro.core import merge as merge_lib
 from repro.core.models import KGModel, Params, get_model
-from repro.parallel.util import shard_map, worker_map
+from repro.parallel.util import worker_map
 
 RankMetrics = host_eval.RankMetrics
 
@@ -378,7 +378,7 @@ def _entity_ranks_sharded(
         _, outs = jax.lax.scan(body, None, (q_all, tc_all, hc_all))
         return outs
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(), P(), P(), P()), out_specs=P(), check_vma=False)
     return fn(params, queries, tail_cands, head_cands)

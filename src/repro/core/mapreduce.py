@@ -121,7 +121,7 @@ from repro.core import trace as trace_lib
 from repro.core.models.base import EpochStats, KGConfig, KGModel, Params, apply_gradients
 from repro.data import kg as kg_lib
 from repro.parallel.sharding import kg_partitions, kg_table_shardings
-from repro.parallel.util import all_gather_deltas, shard_map as _shard_map
+from repro.parallel.util import all_gather_deltas
 from repro.util import warn_fresh
 
 
@@ -808,7 +808,7 @@ def sgd_epoch_shard(
             return out, loss, overflow
         return out, loss
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         worker,
         mesh=mesh,
         in_specs=(P(), P(ax), P(ax)),
@@ -1023,7 +1023,7 @@ def bgd_epoch_shard(
         return _bgd_epoch_collective(
             model, cfg, tcfg, params, pos_w[0], neg_w[0])
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         worker, mesh=mesh,
         in_specs=(P(), P(ax), P(ax)), out_specs=(P(), P()),
         check_vma=False,
@@ -1392,7 +1392,7 @@ def make_block_fn(
                 return params, losses.reshape(-1), ovf
             return params, losses.reshape(-1)
 
-        fn = _shard_map(
+        fn = jax.shard_map(
             worker, mesh=mesh,
             in_specs=(P(), P(ax), P()),
             out_specs=(P(), P(), P()) if with_overflow else (P(), P()),
@@ -1452,7 +1452,7 @@ def make_block_fn(
             return (g, local), losses.reshape(-1)
 
         state_specs = (P(), P(ax))
-        fn = _shard_map(
+        fn = jax.shard_map(
             worker, mesh=mesh,
             in_specs=(state_specs, P(ax), P()),
             out_specs=(
@@ -1474,7 +1474,7 @@ def make_block_fn(
 
             return jax.lax.scan(epoch_body, params, epoch_ids)
 
-        fn = _shard_map(
+        fn = jax.shard_map(
             worker, mesh=mesh,
             in_specs=(P(), P(ax), P()), out_specs=(P(), P()),
             check_vma=False,
@@ -1504,32 +1504,28 @@ def make_block_fn(
             out, losses = inner_bgd(params, epoch_ids)
             return out, losses, jnp.zeros((), jnp.int32)
 
+    out_shardings = None
     if cfg.table_sharding == "sharded" and cfg.backend == "shard_map":
         # rest the tables row-sharded over the mesh axis between blocks:
-        # _train_device places the input params P(axis) and this output
-        # constraint keeps the donated in/out layouts matched, so
+        # _train_device places the input params P(axis) and these output
+        # shardings keep the donated in/out layouts matched, so
         # per-device table residency stays ~1/W across the run (inside a
         # block the Map still gathers full tables — see ROADMAP's
-        # sharded-tables item for the fully shard-resident follow-on)
-        inner_layout = fn
+        # sharded-tables item for the fully shard-resident follow-on).
+        # jit's out_shardings (unlike with_sharding_constraint) take both
+        # Auto and Explicit mesh axes — jax.make_mesh defaults to Explicit
+        tables = kg_table_shardings(
+            model.param_roles(),
+            jax.eval_shape(lambda k: model.init_params(k, tcfg),
+                           jax.random.PRNGKey(0)),
+            mesh, "sharded", axis_name=ax)
+        # staleness>0 threads (global_view, worker_locals): only the
+        # global view rests row-sharded (locals are already P(ax)-stacked)
+        state = (tables, None) if S > 0 else tables
+        out_shardings = (state, None) + ((None,) if with_overflow else ())
 
-        def fn(params, epoch_ids):
-            res = inner_layout(params, epoch_ids)
-            # staleness>0 threads (global_view, worker_locals): constrain
-            # only the global view (locals are already P(ax)-stacked)
-            state = res[0]
-            g = state[0] if isinstance(state, tuple) else state
-            shardings = kg_table_shardings(
-                model.param_roles(), g, mesh, "sharded", axis_name=ax)
-            out = {
-                name: jax.lax.with_sharding_constraint(x, shardings[name])
-                for name, x in g.items()
-            }
-            if isinstance(state, tuple):
-                out = (out, state[1])
-            return (out,) + tuple(res[1:])
-
-    return jax.jit(fn, donate_argnums=(0,) if donate else ())
+    return jax.jit(fn, donate_argnums=(0,) if donate else (),
+                   out_shardings=out_shardings)
 
 
 # ---------------------------------------------------------------------------

@@ -242,7 +242,7 @@ class KGModel:
         *,
         margin: float,
         norm: str,
-        interpret: bool | None = None,
+        interpret: bool = False,
     ) -> jax.Array:
         """Pallas-fused margin loss.  A model declaring
         ``supports_fused_kernel = True`` MUST override this (and
@@ -259,7 +259,7 @@ class KGModel:
         side: str,
         *,
         norm: str,
-        interpret: bool | None = None,
+        interpret: bool = False,
     ) -> jax.Array:
         """Pallas-fused entity-inference rank counts (see fused_margin_loss)."""
         raise NotImplementedError(

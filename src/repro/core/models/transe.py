@@ -123,7 +123,7 @@ class TransE(base.KGModel):
     # -- fused Pallas kernels (late imports: kernels/ops imports this pkg) --
 
     def fused_margin_loss(
-        self, params, pos, neg, *, margin, norm, interpret=None
+        self, params, pos, neg, *, margin, norm, interpret=False
     ):
         from repro.kernels import ops
 
@@ -132,14 +132,12 @@ class TransE(base.KGModel):
         )
 
     def fused_rank_counts(
-        self, params, triplets, side, *, norm, interpret=None
+        self, params, triplets, side, *, norm, interpret=False
     ):
         """Streaming rank-count kernel: q = h + r (tail) / t - r (head),
         count entities strictly closer than the gold."""
-        from repro.kernels import ops, rank_topk
+        from repro.kernels import rank_topk
 
-        if interpret is None:
-            interpret = ops._default_interpret()
         ent, rel = params["ent"], params["rel"]
         h = ent[triplets[:, 0]]
         r = rel[triplets[:, 1]]

@@ -342,16 +342,18 @@ def synthetic_kg(
     h = rng.integers(0, n_entities, size=n_draw)
     r = rng.integers(0, n_relations, size=n_draw)
     target = z[h] + g[r] + rng.normal(scale=noise, size=(n_draw, latent_dim))
-    # nearest entity by blocked L2 search (keeps memory bounded)
+    # nearest entity by blocked L2 search: |tb|² - 2 tb·z + |z|², built in
+    # one (block, E) buffer per block (in place, so the blocks stay in
+    # cache — the same float64 operations in the same order as the plain
+    # expression, hence the same argmin)
     t = np.empty((n_draw,), np.int64)
-    block = 4096
+    zz = np.sum(z * z, axis=1)[None, :]
+    block = 256
     for i in range(0, n_draw, block):
         tb = target[i : i + block]
-        d = (
-            np.sum(tb * tb, axis=1, keepdims=True)
-            - 2.0 * tb @ z.T
-            + np.sum(z * z, axis=1)[None, :]
-        )
+        d = (2.0 * tb) @ z.T
+        np.subtract(np.sum(tb * tb, axis=1, keepdims=True), d, out=d)
+        d += zz
         t[i : i + block] = np.argmin(d, axis=1)
 
     triplets = np.stack([h, r, t], axis=1).astype(np.int32)

@@ -18,8 +18,11 @@ TPU adaptation:
     semantics: the count block's index_map ignores the entity-tile index, so
     it stays resident in VMEM while the inner grid dimension sweeps E.
 
-VMEM budget (fp32): q (TB, k) + table tile (TE, k) + L1 intermediate
-(TB, TE) — with TB=256, TE=512, k=128: 128 KB + 256 KB + 512 KB « 16 MB.
+VMEM: Mosaic gives a kernel a scoped VMEM stack (16 MiB on v5e), and the
+largest live value of the L1 body is its ``|diff|`` tensor.  The body
+reduces ``k`` in slices of ``LANES`` columns, so that tensor is
+``(TB, TE, LANES)`` whatever ``k`` is, and :func:`_tiles` sizes ``TB`` so
+it stays within ``L1_DIFF_BUDGET`` — the one place the budget is set.
 """
 from __future__ import annotations
 
@@ -32,6 +35,24 @@ from jax.experimental import pallas as pl
 
 DEFAULT_TB = 256   # query tile (rows)
 DEFAULT_TE = 512   # entity-table tile (rows)
+LANES = 128        # L1 reduces k in slices this wide (one vreg of lanes)
+# bytes of the L1 body's (TB, TE, LANES) fp32 |diff| slice: a quarter of
+# v5e's 16 MiB scoped VMEM leaves room for the double-buffered input tiles
+# and Mosaic's own temporaries (a 16.6 MiB stack at TB=256, TE=128 was
+# refused when the whole of k was reduced at once)
+L1_DIFF_BUDGET = 4 * 1024 * 1024
+
+
+def _tiles(B: int, E: int, norm: str) -> tuple[int, int]:
+    """(tb, te) for a ``(B, k)`` query block against an ``(E, k)`` table:
+    the default tiles, clamped to the problem, with ``tb`` cut for L1 so
+    the ``|diff|`` slice fits ``L1_DIFF_BUDGET``.  Tiles stay multiples of
+    8 (the sublane count) unless a tile spans its whole axis."""
+    te = min(DEFAULT_TE, max(8, E))
+    tb = DEFAULT_TB
+    if norm == "l1":
+        tb = max(8, L1_DIFF_BUDGET // (te * LANES * 4) // 8 * 8)
+    return min(tb, max(8, B)), te
 
 
 def _kernel(q_ref, tab_ref, gold_ref, cnt_ref, *, norm: str):
@@ -41,14 +62,21 @@ def _kernel(q_ref, tab_ref, gold_ref, cnt_ref, *, norm: str):
     def _init():
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    q = q_ref[...].astype(jnp.float32)          # (TB, k)
-    tab = tab_ref[...].astype(jnp.float32)      # (TE, k)
     gold = gold_ref[...].astype(jnp.float32)    # (TB, 1)
 
     if norm == "l1":
-        # (TB, TE, k) lives only in VREG/VMEM for this tile pair
-        d = jnp.sum(jnp.abs(q[:, None, :] - tab[None, :, :]), axis=-1)
+        # reduce k one LANES-wide slice at a time (k is padded to a
+        # multiple of LANES): the live |diff| tensor is (TB, TE, LANES)
+        # however wide the embedding is
+        d = None
+        for lo in range(0, q_ref.shape[1], LANES):
+            q = q_ref[:, lo:lo + LANES].astype(jnp.float32)
+            tab = tab_ref[:, lo:lo + LANES].astype(jnp.float32)
+            part = jnp.sum(jnp.abs(q[:, None, :] - tab[None, :, :]), axis=-1)
+            d = part if d is None else d + part
     else:
+        q = q_ref[...].astype(jnp.float32)          # (TB, k)
+        tab = tab_ref[...].astype(jnp.float32)      # (TE, k)
         qq = jnp.sum(q * q, axis=-1, keepdims=True)              # (TB, 1)
         tt = jnp.sum(tab * tab, axis=-1)[None, :]                # (1, TE)
         # MXU contraction
@@ -68,24 +96,29 @@ def rank_counts(
     gold_d: jax.Array,         # (B,)
     *,
     norm: str = "l1",
-    tb: int = DEFAULT_TB,
-    te: int = DEFAULT_TE,
+    tb: int | None = None,
+    te: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Count of entities strictly closer than gold, per query: (B,) int32.
     rank = 1 + count.  Inputs are padded here; pad rows of the table get
-    +inf-like distances and never count."""
+    +inf-like distances and never count.  ``tb``/``te`` override the tiles
+    :func:`_tiles` picks."""
     B, k = queries.shape
     E = table.shape[0]
 
-    tb = min(tb, max(8, B))
-    te = min(te, max(8, E))
+    auto_tb, auto_te = _tiles(B, E, norm)
+    tb = auto_tb if tb is None else min(tb, max(8, B))
+    te = auto_te if te is None else min(te, max(8, E))
     Bp = -(-B // tb) * tb
     Ep = -(-E // te) * te
 
-    qp = jnp.zeros((Bp, k), queries.dtype).at[:B].set(queries)
+    # L1 slices k LANES columns at a time: zero columns add exactly 0
+    kp = -(-k // LANES) * LANES if norm == "l1" else k
+    qp = jnp.zeros((Bp, kp), queries.dtype).at[:B, :k].set(queries)
     # pad entities FAR away: distance to anything is huge -> never "closer"
-    tp = jnp.full((Ep, k), 1e9, table.dtype).at[:E].set(table)
+    tp = (jnp.zeros((Ep, kp), table.dtype).at[:, :k].set(1e9)
+          .at[:E, :k].set(table))
     gp = jnp.zeros((Bp, 1), jnp.float32).at[:B, 0].set(gold_d.astype(jnp.float32))
 
     grid = (Bp // tb, Ep // te)
@@ -94,8 +127,8 @@ def rank_counts(
         functools.partial(_kernel, norm=norm),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tb, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((te, k), lambda i, j: (j, 0)),
+            pl.BlockSpec((tb, kp), lambda i, j: (i, 0)),
+            pl.BlockSpec((te, kp), lambda i, j: (j, 0)),
             pl.BlockSpec((tb, 1), lambda i, j: (i, 0)),
         ],
         out_specs=pl.BlockSpec((tb, 1), lambda i, j: (i, 0)),

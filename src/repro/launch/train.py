@@ -8,11 +8,14 @@ KG embedding runs route through the model-agnostic `repro.kg` facade:
     PYTHONPATH=src python -m repro.launch.train --kg distmult \
         --kg-paradigm bgd --kg-workers 4 --kg-epochs 30
 
-On real hardware the same entry point runs the full config on the
-production mesh (--mesh pod|single); on this CPU container use --reduced.
-For multi-host TPU, initialize jax.distributed before calling main() (the
-launcher auto-detects via JAX_COORDINATOR env) — the mesh/sharding code is
-topology-agnostic.
+On an accelerator the same entry point runs the full config on the
+production mesh (--mesh pod|single); on a CPU use --reduced.  The launcher
+does not initialize jax.distributed: for multi-host TPU, call
+``jax.distributed.initialize(coordinator_address=..., num_processes=...,
+process_id=...)`` before ``main()`` — the mesh/sharding code is
+topology-agnostic.  The KG path (--kg) trains on the local devices with
+the vmap backend.  ``main()`` turns on the persistent compilation cache
+(``repro.compile_cache``).
 
 The paper's cross-pod MapReduce training is enabled with --outer-sync H
 (average merge, int8-compressed deltas) — see core/local_sgd.py.
@@ -23,7 +26,7 @@ import argparse
 
 import jax
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.data.tokens import TokenPipeline, TokenPipelineConfig
 from repro.models import registry
 from repro.train import loop as loop_lib, optimizer as opt_lib
@@ -392,6 +395,7 @@ def main(argv=None):
                     help="'none' = local devices unsharded")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.kg:
         _run_kg(args)

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from repro.models import common
 from repro.models.common import ModelConfig, dense_init
 from repro.parallel.util import constrain as _constrain_axes
-from repro.parallel.util import shard_map as _shard_map
 
 
 def _constrain(x, axes):
@@ -252,7 +251,7 @@ def _apply_moe_shardmap(p, x: jax.Array, cfg: ModelConfig, mesh, dp):
 
     in_specs = (P(dp, None), P(None, None), wspec, wspec, wospec)
     out_specs = (P(dp, None), P())
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         worker, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )(x_flat, p["router"], p["wg"], p["wi"], p["wo"])

@@ -16,24 +16,6 @@ from jax.sharding import PartitionSpec as P
 AxisLike = Union[None, str, Tuple[str, ...]]
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-compat ``shard_map``: the top-level ``jax.shard_map`` (with
-    ``check_vma``) where it exists, else the ``jax.experimental`` one (whose
-    equivalent knob is ``check_rep``).  Keeps the engine importable across
-    the jax versions this repo meets (0.4.x containers through current)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
 def worker_map(fn, *, backend: str, mesh=None, axis_name: str = "workers"):
     """Lift ``fn(broadcast, *per_worker)`` over a leading worker axis.
 
@@ -67,7 +49,7 @@ def worker_map(fn, *, backend: str, mesh=None, axis_name: str = "workers"):
         def worker(broadcast, *xs):
             return jax.vmap(lambda *ys: fn(broadcast, *ys))(*xs)
 
-        f = shard_map(
+        f = jax.shard_map(
             worker, mesh=mesh,
             in_specs=(P(),) + (P(axis_name),) * len(sharded),
             out_specs=P(axis_name), check_vma=False,

@@ -47,7 +47,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import eval_device
 from repro.core import merge as merge_lib
 from repro.core.models import KGModel, Params, get_model
-from repro.parallel.util import shard_map, worker_map
+from repro.parallel.util import worker_map
 
 DEFAULT_CHUNK = eval_device.DEFAULT_CHUNK
 
@@ -204,7 +204,7 @@ def _entity_topk_sharded(
         _, out = jax.lax.scan(body, None, (q_all, ex_all))
         return out
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(), P(), P()), out_specs=P(), check_vma=False)
     return fn(params, queries, exclude)

@@ -50,7 +50,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import local_sgd, mapreduce, negative, transe
 from repro.data import kg as kg_lib
-from repro.parallel.util import shard_map
 
 W = 8
 assert len(jax.devices()) == W, f"expected {W} devices, got {len(jax.devices())}"
@@ -121,7 +120,7 @@ def check_outer_merge():
             )
             return merged[None]
 
-        out = jax.jit(shard_map(
+        out = jax.jit(jax.shard_map(
             f, mesh=mesh,
             in_specs=(P("pod"), P("pod"), P("pod")),
             out_specs=P("pod"), check_vma=False,

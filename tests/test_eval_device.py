@@ -248,17 +248,21 @@ def test_fused_true_requires_kernel(tiny_kg, tiny_params):
 
 
 def test_fused_kernel_path_matches_reference(tiny_kg, tiny_params):
-    """The rank_topk Pallas path (interpret mode off-TPU) against the exact
-    jnp path — kernel-test tolerance: identical up to last-ulp tie flips."""
+    """The rank_topk Pallas path against the exact jnp path — kernel-test
+    tolerance: identical up to last-ulp tie flips.  Off TPU the kernel only
+    runs interpreted, which this test asks for explicitly."""
+    from jax.experimental.pallas import tpu as pltpu
+
     masks = tiny_kg.eval_filter_candidates()
     test = tiny_kg.test[:48]
     tmasks = (masks[0][:48], masks[1][:48])
     exact = eval_device.entity_ranks_device(
         tiny_params["transe"], test, "l1", tmasks, model="transe",
         fused=False)
-    fused = eval_device.entity_ranks_device(
-        tiny_params["transe"], test, "l1", tmasks, model="transe",
-        fused=True)
+    with pltpu.force_tpu_interpret_mode():
+        fused = eval_device.entity_ranks_device(
+            tiny_params["transe"], test, "l1", tmasks, model="transe",
+            fused=True)
     for grp in ("raw_ranks", "filtered_ranks"):
         for side in ("tail", "head"):
             diff = np.abs(exact[grp][side].astype(np.int64)

@@ -1,0 +1,103 @@
+"""Compiles of the main path for a described TPU v5e chip.
+
+Nothing runs here: each test lowers and compiles for the first chip of a
+described ``v5e:2x2`` topology, which refuses what the chip's compiler
+would refuse — a kernel over its scoped VMEM, an unaligned block, a
+program larger than the chip's 16 GB — with no chip attached.  Interpret
+mode, which every other kernel test uses, checks none of that.
+
+Shapes are ``chip_smoke.py``'s deployment: FB15k's entity and relation
+counts at dim 400.  The topology is described inside a fixture and never
+at import time: only one process at a time may load the TPU library.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import chip_smoke as smoke
+from repro import kg
+from repro.core import eval_device, mapreduce
+from repro.core.models import get_model
+from repro.data import kg as kg_lib
+from repro.kernels import rank_topk
+
+HBM_BYTES = 16 * 10**9      # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back without one
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _spec(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _tables(sharding):
+    k = smoke.FIT["dim"]
+    return {"ent": _spec(sharding, (smoke.N_ENTITIES, k)),
+            "rel": _spec(sharding, (smoke.N_RELATIONS, k))}
+
+
+# query rows = the eval chunk of 256 split over W in {1, 2, 4, 8} workers
+@pytest.mark.parametrize("norm", ["l1", "l2"])
+@pytest.mark.parametrize("k", [50, 400])
+@pytest.mark.parametrize("rows", [32, 64, 128, 256])
+def test_rank_counts_compiles(one_chip, rows, k, norm):
+    E = smoke.N_ENTITIES
+    fn = jax.jit(lambda q, t, g: rank_topk.rank_counts(
+        q, t, g, norm=norm, interpret=False))
+    compiled = fn.lower(_spec(one_chip, (rows, k)), _spec(one_chip, (E, k)),
+                        _spec(one_chip, (rows,))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_default_eval_program_holds_kernel(one_chip):
+    """The device eval the smoke runs by default (one worker, the fused
+    TransE path) compiles with the Mosaic kernel inside it."""
+    S, C, _ = eval_device._layout(
+        smoke.N_EVAL, eval_device.DEFAULT_CHUNK, 1)
+    ids = _spec(one_chip, (1, S, C, 3), jnp.int32)
+    cands = _spec(one_chip, (1, S, C, 8), jnp.int32)
+    compiled = eval_device._entity_ranks_device.lower(
+        get_model("transe"), _tables(one_chip), ids, cands, cands,
+        norm="l1", backend="vmap", mesh=None, axis_name="workers",
+        fused=True, relations=True).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_training_block_fits_one_chip(one_chip):
+    """One device-pipeline epoch block at the smoke's configuration
+    compiles and needs less than one chip's memory."""
+    empty = np.zeros((0, 3), np.int32)
+    graph = kg_lib.KG(smoke.N_ENTITIES, smoke.N_RELATIONS, empty, empty,
+                      empty)
+    fit_kw = {n: v for n, v in smoke.FIT.items()
+              if n not in ("model", "paradigm")}
+    kcfg, mcfg = kg.make_configs(graph, "transe", "sgd", **fit_kw)
+    W = mcfg.n_workers
+    partitioned = np.random.default_rng(0).integers(
+        0, smoke.N_ENTITIES, size=(W, smoke.N_TRAIN // W, 3)).astype(np.int32)
+    block = mapreduce.make_block_fn(
+        mcfg, kcfg, partitioned, model=get_model("transe"), donate=True)
+    compiled = block.lower(_tables(one_chip),
+                           _spec(one_chip, (1,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 0 < total < HBM_BYTES, mem
