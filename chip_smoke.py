@@ -45,6 +45,8 @@ from pathlib import Path
 import jax
 import numpy as np
 
+from bench.harness import CompileClock
+
 ROOT = Path(__file__).resolve().parent
 
 # FB15k (Bordes et al. 2013, Table 1)
@@ -69,28 +71,6 @@ class SmokeFailure(RuntimeError):
 def check(ok: bool, what: str) -> None:
     if not ok:
         raise SmokeFailure(what)
-
-
-class CompileClock:
-    """Seconds XLA spent compiling in this process, the programs compiled
-    and how many of them the persistent cache held (JAX's own monitoring
-    events; the seconds include reads from the cache)."""
-
-    EVENT = "/jax/core/compile/backend_compile_duration"
-    HIT = "/jax/compilation_cache/cache_hits"
-
-    def __init__(self):
-        self.seconds, self.programs, self.hits = 0.0, 0, 0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-        jax.monitoring.register_event_listener(self._on_hit)
-
-    def _on(self, event: str, duration: float, **_) -> None:
-        if event == self.EVENT:
-            self.seconds += duration
-            self.programs += 1
-
-    def _on_hit(self, event: str, **_) -> None:
-        self.hits += event == self.HIT
 
 
 class Phase:

@@ -53,6 +53,7 @@ import numpy as np
 
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core import eval as host_eval
 from repro.core import merge as merge_lib
 from repro.core.models import KGModel, Params, get_model
@@ -153,31 +154,48 @@ def _entity_chunk(
     E = params["ent"].shape[0]
     gold_ids = chunk[:, 2] if side == "tail" else chunk[:, 0]
     if fused:
-        raw_counts = model.fused_rank_counts(params, chunk, side, norm=norm)
-        raw = 1 + raw_counts.astype(jnp.int32)
+        with obs.scope("eval.scan"):
+            raw_counts = model.fused_rank_counts(
+                params, chunk, side, norm=norm)
+            raw = 1 + raw_counts.astype(jnp.int32)
         # candidate scores via substituted-triplet energies (the kernel
         # never materializes the (C, E) matrix); gold recomputed the same way
-        col = 2 if side == "tail" else 0
-        subst = jnp.broadcast_to(
-            chunk[:, None, :], cands.shape + (3,)
-        ).at[:, :, col].set(jnp.minimum(cands, E - 1))
-        cvals = model.energy(params, subst, norm)
-        cvals = jnp.where(cands >= E, jnp.inf, cvals)
-        gold = model.energy(params, chunk, norm)
+        with obs.scope("eval.filter"):
+            col = 2 if side == "tail" else 0
+            subst = jnp.broadcast_to(
+                chunk[:, None, :], cands.shape + (3,)
+            ).at[:, :, col].set(jnp.minimum(cands, E - 1))
+            cvals = model.energy(params, subst, norm)
+            cvals = jnp.where(cands >= E, jnp.inf, cvals)
+        with obs.scope("eval.scan"):
+            gold = model.energy(params, chunk, norm)
     else:
-        scores = model.candidate_energies(params, chunk, side, norm)
-        gold = scores[jnp.arange(scores.shape[0]), gold_ids]
-        raw = 1 + jnp.sum(scores < gold[:, None], axis=1).astype(jnp.int32)
+        with obs.scope("eval.scan"):
+            scores = model.candidate_energies(params, chunk, side, norm)
+            gold = scores[jnp.arange(scores.shape[0]), gold_ids]
+            raw = 1 + jnp.sum(
+                scores < gold[:, None], axis=1).astype(jnp.int32)
         # pad ids (== E) gather a clamped column, then read +inf — no
         # (C, E+1) copy of the score matrix inside the scan body
-        cvals = jnp.take_along_axis(
-            scores, jnp.minimum(cands, E - 1), axis=1)
-        cvals = jnp.where(cands >= E, jnp.inf, cvals)
-    better = (cvals < gold[:, None]) & (cands != gold_ids[:, None])
-    filt = raw - jnp.sum(better, axis=1).astype(jnp.int32)
+        with obs.scope("eval.filter"):
+            cvals = jnp.take_along_axis(
+                scores, jnp.minimum(cands, E - 1), axis=1)
+            cvals = jnp.where(cands >= E, jnp.inf, cvals)
+    with obs.scope("eval.filter"):
+        better = (cvals < gold[:, None]) & (cands != gold_ids[:, None])
+        filt = raw - jnp.sum(better, axis=1).astype(jnp.int32)
     # the fused path recomputes distances and can disagree with the raw
     # count in the last ulp; ranks are >= 1 by construction on the exact path
     return raw, jnp.maximum(filt, 1)
+
+
+@obs.scope("eval.relations")
+def _relation_ranks(model: KGModel, params: Params, q: jax.Array,
+                    norm: str) -> jax.Array:
+    """(C,) rank of each query's gold relation among all relations."""
+    scores = model.relation_energies(params, q, norm)
+    gold = scores[jnp.arange(scores.shape[0]), q[:, 1]]
+    return 1 + jnp.sum(scores < gold[:, None], axis=1).astype(jnp.int32)
 
 
 @functools.partial(
@@ -217,10 +235,7 @@ def _entity_ranks_device(
                 "head_raw": raw_h, "head_filtered": filt_h,
             }
             if relations:
-                scores = model.relation_energies(params, q, norm)
-                gold = scores[jnp.arange(scores.shape[0]), q[:, 1]]
-                out["relation"] = 1 + jnp.sum(
-                    scores < gold[:, None], axis=1).astype(jnp.int32)
+                out["relation"] = _relation_ranks(model, params, q, norm)
             return None, out
 
         _, outs = jax.lax.scan(body, None, (q_w, tc_w, hc_w))
@@ -295,11 +310,6 @@ def _entity_ranks_sharded(
     R = merge_lib.shard_rows(E, W)
     cdtype = queries.dtype
 
-    def relation_out(q):
-        scores = model.relation_energies(params, q, norm)
-        gold = scores[jnp.arange(scores.shape[0]), q[:, 1]]
-        return 1 + jnp.sum(scores < gold[:, None], axis=1).astype(jnp.int32)
-
     if backend == "vmap":
         los = (jnp.arange(W, dtype=cdtype) * R).astype(cdtype)
         cols = los[:, None] + jnp.arange(R, dtype=cdtype)[None, :]  # (W, R)
@@ -307,21 +317,23 @@ def _entity_ranks_sharded(
 
         def side_ranks(q, cands, side):
             gold_ids = q[:, 2] if side == "tail" else q[:, 0]
-            s_all, gp_all = jax.vmap(
-                lambda lo: _shard_slice_parts(
-                    model, params, q, side, norm, gold_ids, lo, R)
-            )(los)                               # (W, C, R), (W, C)
-            gold = jnp.min(gp_all, axis=0)
-            raw = 1 + jnp.sum(
-                (s_all < gold[None, :, None]) & live[:, None, :],
-                axis=(0, 2)).astype(jnp.int32)
-            c_off = cands[None, :, :] - los[:, None, None]
-            inr = (c_off >= 0) & (c_off < R) & (cands[None] < E)
-            cv = jnp.take_along_axis(
-                s_all, jnp.clip(c_off, 0, R - 1), axis=2)
-            better = (inr & (cv < gold[None, :, None])
-                      & (cands[None] != gold_ids[None, :, None]))
-            filt = raw - jnp.sum(better, axis=(0, 2)).astype(jnp.int32)
+            with obs.scope("eval.scan"):
+                s_all, gp_all = jax.vmap(
+                    lambda lo: _shard_slice_parts(
+                        model, params, q, side, norm, gold_ids, lo, R)
+                )(los)                           # (W, C, R), (W, C)
+                gold = jnp.min(gp_all, axis=0)
+                raw = 1 + jnp.sum(
+                    (s_all < gold[None, :, None]) & live[:, None, :],
+                    axis=(0, 2)).astype(jnp.int32)
+            with obs.scope("eval.filter"):
+                c_off = cands[None, :, :] - los[:, None, None]
+                inr = (c_off >= 0) & (c_off < R) & (cands[None] < E)
+                cv = jnp.take_along_axis(
+                    s_all, jnp.clip(c_off, 0, R - 1), axis=2)
+                better = (inr & (cv < gold[None, :, None])
+                          & (cands[None] != gold_ids[None, :, None]))
+                filt = raw - jnp.sum(better, axis=(0, 2)).astype(jnp.int32)
             return raw, jnp.maximum(filt, 1)
 
         def body(_, inp):
@@ -333,7 +345,7 @@ def _entity_ranks_sharded(
                 "head_raw": raw_h, "head_filtered": filt_h,
             }
             if relations:
-                out["relation"] = relation_out(q)
+                out["relation"] = _relation_ranks(model, params, q, norm)
             return None, out
 
         _, outs = jax.lax.scan(
@@ -346,19 +358,22 @@ def _entity_ranks_sharded(
 
         def side_ranks(q, cands, side):
             gold_ids = q[:, 2] if side == "tail" else q[:, 0]
-            s, gp = _shard_slice_parts(
-                model, params, q, side, norm, gold_ids, lo, R)
-            gold = jax.lax.pmin(gp, axis_name)
-            cnt = jnp.sum((s < gold[:, None]) & live[None, :],
-                          axis=1).astype(jnp.int32)
-            raw = 1 + jax.lax.psum(cnt, axis_name)
-            c_off = cands - lo
-            inr = (c_off >= 0) & (c_off < R) & (cands < E)
-            cv = jnp.take_along_axis(s, jnp.clip(c_off, 0, R - 1), axis=1)
-            better = (inr & (cv < gold[:, None])
-                      & (cands != gold_ids[:, None]))
-            filt = raw - jax.lax.psum(
-                jnp.sum(better, axis=1).astype(jnp.int32), axis_name)
+            with obs.scope("eval.scan"):
+                s, gp = _shard_slice_parts(
+                    model, params, q, side, norm, gold_ids, lo, R)
+                gold = jax.lax.pmin(gp, axis_name)
+                cnt = jnp.sum((s < gold[:, None]) & live[None, :],
+                              axis=1).astype(jnp.int32)
+                raw = 1 + jax.lax.psum(cnt, axis_name)
+            with obs.scope("eval.filter"):
+                c_off = cands - lo
+                inr = (c_off >= 0) & (c_off < R) & (cands < E)
+                cv = jnp.take_along_axis(
+                    s, jnp.clip(c_off, 0, R - 1), axis=1)
+                better = (inr & (cv < gold[:, None])
+                          & (cands != gold_ids[:, None]))
+                filt = raw - jax.lax.psum(
+                    jnp.sum(better, axis=1).astype(jnp.int32), axis_name)
             return raw, jnp.maximum(filt, 1)
 
         def body(_, inp):
@@ -372,7 +387,7 @@ def _entity_ranks_sharded(
             if relations:
                 # every shard computes the full relation scan identically
                 # (the relation table is never sharded)
-                out["relation"] = relation_out(q)
+                out["relation"] = _relation_ranks(model, params, q, norm)
             return None, out
 
         _, outs = jax.lax.scan(body, None, (q_all, tc_all, hc_all))
@@ -443,33 +458,39 @@ def entity_ranks_device(
     else:
         tails, heads = cand_masks
     layout_W = 1 if sharded else W
-    q = _shard(_pad_rows(test, Qp), layout_W, S, C)
-    tc = _shard(_pad_rows(np.asarray(tails, np.int32), Qp), layout_W, S, C)
-    hc = _shard(_pad_rows(np.asarray(heads, np.int32), Qp), layout_W, S, C)
+    with obs.span("eval.layout"):
+        q = _shard(_pad_rows(test, Qp), layout_W, S, C)
+        tc = _shard(_pad_rows(np.asarray(tails, np.int32), Qp),
+                    layout_W, S, C)
+        hc = _shard(_pad_rows(np.asarray(heads, np.int32), Qp),
+                    layout_W, S, C)
+        if sharded:
+            _check_sharded_mesh(backend, mesh, W)
+            R = merge_lib.shard_rows(E, W)
+            params = _pad_ent_tables(model, params, W * R)
 
-    if sharded:
-        _check_sharded_mesh(backend, mesh, W)
-        R = merge_lib.shard_rows(E, W)
-        padded = _pad_ent_tables(model, params, W * R)
-        outs = _entity_ranks_sharded(
-            model, padded, q[0], tc[0], hc[0], norm=norm, backend=backend,
-            mesh=mesh, axis_name="workers", n_shards=W, n_entities=E,
-            relations=relations)
-    else:
-        outs = _entity_ranks_device(
-            model, params, q, tc, hc, norm=norm, backend=backend, mesh=mesh,
-            axis_name="workers", fused=fused, relations=relations)
-    out = {"raw_ranks": {
-        "tail": _unshard(outs["tail_raw"], Q),
-        "head": _unshard(outs["head_raw"], Q),
-    }}
-    if cand_masks is not None:
-        out["filtered_ranks"] = {
-            "tail": _unshard(outs["tail_filtered"], Q),
-            "head": _unshard(outs["head_filtered"], Q),
-        }
-    if relations:
-        out["relation_ranks"] = _unshard(outs["relation"], Q)
+    with obs.span("eval.ranks"):
+        if sharded:
+            outs = _entity_ranks_sharded(
+                model, params, q[0], tc[0], hc[0], norm=norm,
+                backend=backend, mesh=mesh, axis_name="workers",
+                n_shards=W, n_entities=E, relations=relations)
+        else:
+            outs = _entity_ranks_device(
+                model, params, q, tc, hc, norm=norm, backend=backend,
+                mesh=mesh, axis_name="workers", fused=fused,
+                relations=relations)
+        out = {"raw_ranks": {
+            "tail": _unshard(outs["tail_raw"], Q),
+            "head": _unshard(outs["head_raw"], Q),
+        }}
+        if cand_masks is not None:
+            out["filtered_ranks"] = {
+                "tail": _unshard(outs["tail_filtered"], Q),
+                "head": _unshard(outs["head_filtered"], Q),
+            }
+        if relations:
+            out["relation_ranks"] = _unshard(outs["relation"], Q)
     return out
 
 
@@ -539,10 +560,7 @@ def _relation_ranks_device(
 ) -> jax.Array:
     def per_worker(params, q_w):
         def body(_, q):
-            scores = model.relation_energies(params, q, norm)
-            gold = scores[jnp.arange(scores.shape[0]), q[:, 1]]
-            return None, 1 + jnp.sum(
-                scores < gold[:, None], axis=1).astype(jnp.int32)
+            return None, _relation_ranks(model, params, q, norm)
 
         _, ranks = jax.lax.scan(body, None, q_w)
         return ranks
@@ -584,6 +602,7 @@ def relation_prediction_device(
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("model", "norm"))
+@obs.scope("eval.classify")
 def _tc_scores(model: KGModel, params: Params, triplets: jax.Array, norm: str):
     return model.energy(params, triplets, norm)
 
@@ -606,17 +625,19 @@ def triplet_classification_device(
     ``evaluate_all_device`` passes it so the per-Reduce in-loop eval skips
     the corruption dispatches."""
     model = get_model(model)
-    valid_neg, test_neg = (
-        negatives if negatives is not None
-        else host_eval._tc_negatives(valid, test, n_entities, seed))
-    sections = np.cumsum([len(valid), len(valid_neg), len(test)])
-    allt = jnp.asarray(
-        np.concatenate([valid, valid_neg, test, test_neg], axis=0))
+    with obs.span("eval.classify_host"):
+        valid_neg, test_neg = (
+            negatives if negatives is not None
+            else host_eval._tc_negatives(valid, test, n_entities, seed))
+        sections = np.cumsum([len(valid), len(valid_neg), len(test)])
+        allt = jnp.asarray(
+            np.concatenate([valid, valid_neg, test, test_neg], axis=0))
     scores = np.asarray(_tc_scores(model, params, allt, norm))
     sv_pos, sv_neg, st_pos, st_neg = np.split(scores, sections)
-    return host_eval._threshold_accuracy(
-        sv_pos, sv_neg, st_pos, st_neg, valid, valid_neg, test, test_neg,
-        int(params["rel"].shape[0]))
+    with obs.span("eval.classify_host"):
+        return host_eval._threshold_accuracy(
+            sv_pos, sv_neg, st_pos, st_neg, valid, valid_neg, test,
+            test_neg, int(params["rel"].shape[0]))
 
 
 # ---------------------------------------------------------------------------
@@ -656,25 +677,34 @@ def evaluate_all_device(
     ``table_sharding="sharded"`` swaps in the shard-local candidate scan
     (exact cross-shard combine — metrics unchanged bitwise)."""
     model = get_model(model)
-    masks = kg.eval_filter_candidates(max_fanout) if filtered else None
+    masks = None
+    if filtered:
+        with obs.span("eval.masks"):
+            masks = kg.eval_filter_candidates(max_fanout)
+        cells, known = kg.eval_filter_counts(max_fanout)
+        obs.count("eval.filter_cells", cells)
+        obs.count("eval.filter_known", known)
     ranks = entity_ranks_device(
         params, kg.test, norm, masks, model=model, chunk=chunk,
         n_workers=n_workers, backend=backend, mesh=mesh, fused=fused,
         relations=True, table_sharding=table_sharding)
-    raw = ranks["raw_ranks"]
-    rp = host_eval._metrics_from_ranks(ranks["relation_ranks"])
+    with obs.span("eval.classify_host"):
+        negatives = kg.tc_negatives(0)
     tc = triplet_classification_device(
         params, kg.valid, kg.test, kg.n_entities, norm, model=model,
-        negatives=kg.tc_negatives(0),
+        negatives=negatives,
     )
-    out = {
-        "entity_raw": host_eval._metrics_from_ranks(
-            np.concatenate([raw["tail"], raw["head"]])).row(),
-        "relation_prediction": rp.row(),
-        "triplet_classification_acc": tc,
-    }
-    if filtered:
-        filt = ranks["filtered_ranks"]
-        out["entity_filtered"] = host_eval._metrics_from_ranks(
-            np.concatenate([filt["tail"], filt["head"]])).row()
+    raw = ranks["raw_ranks"]
+    with obs.span("eval.metrics"):
+        out = {
+            "entity_raw": host_eval._metrics_from_ranks(
+                np.concatenate([raw["tail"], raw["head"]])).row(),
+            "relation_prediction": host_eval._metrics_from_ranks(
+                ranks["relation_ranks"]).row(),
+            "triplet_classification_acc": tc,
+        }
+        if filtered:
+            filt = ranks["filtered_ranks"]
+            out["entity_filtered"] = host_eval._metrics_from_ranks(
+                np.concatenate([filt["tail"], filt["head"]])).row()
     return out

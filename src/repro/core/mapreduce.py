@@ -114,6 +114,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core import merge as merge_lib
 from repro.core import negative
 from repro.core import models as kg_models
@@ -424,6 +425,7 @@ def _stats_for_role(stats: EpochStats, role: str):
     return stats.rel_count, stats.rel_loss
 
 
+@obs.scope("reduce")
 def _merge_tables_stacked(
     model: KGModel, strategy: str, stacked: Params, stats, merge_key: jax.Array
 ) -> Params:
@@ -494,6 +496,7 @@ def _virgin_repeats(tcfg: KGConfig, n_steps: int, k_epochs: int) -> int:
     return 0
 
 
+@obs.scope("reduce")
 def _merge_tables_sparse_stacked(
     model: KGModel,
     cfg: MapReduceConfig,
@@ -544,6 +547,7 @@ def _merge_tables_sparse_stacked(
     return out, overflow
 
 
+@obs.scope("reduce")
 def _merge_tables_sparse_collective(
     model: KGModel,
     cfg: MapReduceConfig,
@@ -574,7 +578,7 @@ def _merge_tables_sparse_collective(
     names = sorted(local.keys())
     keys = jax.random.split(merge_key, len(names))
     m = _virgin_repeats(tcfg, n_steps, k_epochs)
-    wl = jax.lax.all_gather(worker_loss, cfg.axis_name)          # (W,)
+    wl = merge_lib.all_gather(worker_loss, cfg.axis_name)        # (W,)
     out = {}
     overflow = jnp.zeros((), jnp.int32)
     for name, key in zip(names, keys):
@@ -595,9 +599,10 @@ def _merge_tables_sparse_collective(
                 cfg.strategy, idx, vals, cnt, lss, wl,
                 local[name], base[name],
                 functools.partial(model.normalize_rows, name), m, key)
-    return out, jax.lax.pmax(overflow, cfg.axis_name)
+    return out, merge_lib.pmax(overflow, cfg.axis_name)
 
 
+@obs.scope("reduce")
 def _merge_tables_stale_stacked(
     model: KGModel, strategy: str, stacked: Params, stats, merge_key: jax.Array,
     base: Params,
@@ -619,6 +624,7 @@ def _merge_tables_stale_stacked(
     return out
 
 
+@obs.scope("reduce")
 def _merge_tables_stale_sparse(
     model: KGModel,
     cfg: MapReduceConfig,
@@ -659,6 +665,7 @@ def _merge_tables_stale_sparse(
     return out, overflow
 
 
+@obs.scope("reduce")
 def _merge_tables_stale_collective(
     model: KGModel,
     cfg: MapReduceConfig,
@@ -682,7 +689,7 @@ def _merge_tables_stale_collective(
     names = sorted(local.keys())
     keys = jax.random.split(merge_key, len(names))
     ax = cfg.axis_name
-    wl = jax.lax.all_gather(worker_loss, ax)                      # (W,)
+    wl = merge_lib.all_gather(worker_loss, ax)                    # (W,)
     out = {}
     overflow = jnp.zeros((), jnp.int32)
     for name, key in zip(names, keys):
@@ -699,12 +706,12 @@ def _merge_tables_stale_collective(
                 cfg.strategy, idx, vals, cnt, lss, wl, base[name], ax, key,
                 sharded=cfg.table_sharding == "sharded")
         else:
-            stacked = jax.lax.all_gather(local[name], ax)
-            counts = jax.lax.all_gather(count, ax)
-            losses = jax.lax.all_gather(loss, ax)
+            stacked = merge_lib.all_gather(local[name], ax)
+            counts = merge_lib.all_gather(count, ax)
+            losses = merge_lib.all_gather(loss, ax)
             out[name] = merge_lib.merge_stacked_stale(
                 cfg.strategy, stacked, counts, losses, wl, base[name], key)
-    return out, jax.lax.pmax(overflow, ax)
+    return out, merge_lib.pmax(overflow, ax)
 
 
 def sgd_epoch_vmap(
@@ -728,7 +735,8 @@ def sgd_epoch_vmap(
     run = functools.partial(
         model.run_epoch, cfg=tcfg,
         sparse_apply=cfg.merge_transport == "sparse")
-    stacked, stats = jax.vmap(run, in_axes=(None, 0, 0))(params, pos, neg)
+    with obs.scope("map"):
+        stacked, stats = jax.vmap(run, in_axes=(None, 0, 0))(params, pos, neg)
     overflow = jnp.zeros((), jnp.int32)
     if cfg.merge_transport == "sparse":
         merged, overflow = _merge_tables_sparse_stacked(
@@ -743,6 +751,7 @@ def sgd_epoch_vmap(
     return merged, loss
 
 
+@obs.scope("reduce")
 def _merge_tables_collective(
     model: KGModel,
     cfg: MapReduceConfig,
@@ -792,9 +801,10 @@ def sgd_epoch_shard(
 
     def worker(params, pos_w, neg_w):
         # pos_w: (1, S, B, 3) — this shard's subset
-        local, stats = model.run_epoch(
-            params, pos_w[0], neg_w[0], tcfg,
-            sparse_apply=cfg.merge_transport == "sparse")
+        with obs.scope("map"):
+            local, stats = model.run_epoch(
+                params, pos_w[0], neg_w[0], tcfg,
+                sparse_apply=cfg.merge_transport == "sparse")
         overflow = jnp.zeros((), jnp.int32)
         if cfg.merge_transport == "sparse":
             out, overflow = _merge_tables_sparse_collective(
@@ -1169,11 +1179,18 @@ def make_block_fn(
                 f"merge_transport={cfg.merge_transport!r}, staleness={S}")
         update_mask = {name: jnp.asarray(m, dtype=bool)
                        for name, m in update_mask.items()}
-    run = functools.partial(
+    run_epoch = functools.partial(
         model.run_epoch, cfg=tcfg,
         sparse_apply=cfg.merge_transport == "sparse",
         update_mask=update_mask)
 
+    @obs.scope("map")
+    def run(params: Params, pos: jax.Array, neg: jax.Array):
+        """One worker's local epoch of SGD steps from ``params``: the Map
+        (both backends; ``update_mask`` is None whenever staleness > 0)."""
+        return run_epoch(params, pos, neg)
+
+    @obs.scope("reduce")
     def clamp_frozen(merged: Params, base: Params) -> Params:
         """Clamp frozen rows of a merge round's output back to the round
         input (merge arithmetic — non-pow2 averaging, virgin-row
@@ -1187,6 +1204,7 @@ def make_block_fn(
             for name in merged
         }
 
+    @obs.scope("negatives")
     def block_part(epoch_ids: jax.Array) -> jax.Array:
         """The (W, N_w, 3) partition in effect for this whole block (vmap
         backend): the static split, or re-partition round
@@ -1198,6 +1216,7 @@ def make_block_fn(
         return kg_lib.device_repartition(
             jax.random.fold_in(k_part, r), partitioned, r, strata)
 
+    @obs.scope("negatives")
     def worker_block_part(epoch_ids: jax.Array, w: jax.Array,
                           part_w: jax.Array) -> jax.Array:
         """Worker ``w``'s (N_w, 3) slice of ``block_part`` inside
@@ -1217,6 +1236,7 @@ def make_block_fn(
         rows = jax.lax.dynamic_slice_in_dim(perm, w * n_w, n_w)
         return jnp.take(flat, rows, axis=0)
 
+    @obs.scope("negatives")
     def worker_epoch_data(e: jax.Array, w: jax.Array, part_w: jax.Array):
         """(pos, neg) for worker ``w`` at epoch ``e`` (the shard_map per-
         worker path).  Key contract shared with ``epoch_data`` below — both
@@ -1227,6 +1247,7 @@ def make_block_fn(
         neg = model.make_negatives(kn, pos, tcfg, head_prob)
         return pos, neg
 
+    @obs.scope("negatives")
     def epoch_data(e: jax.Array, part: jax.Array):
         """Stacked (W, S, B, 3) pos/neg for the vmap backend, batched via
         the data layer's ``device_epoch_batches`` (which folds the worker
@@ -1242,16 +1263,22 @@ def make_block_fn(
 
     # -- vmap backend -------------------------------------------------------
 
+    @obs.scope("reduce")
     def _broadcast(params: Params) -> Params:
         return jax.tree.map(
             lambda x: jnp.broadcast_to(x, (W,) + x.shape), params)
+
+    @obs.scope("reduce")
+    def _shared(stacked: Params) -> Params:
+        """Worker 0's copy of every table: after a Reduce all W are equal."""
+        return jax.tree.map(lambda x: x[0], stacked)
 
     def sgd_block_vmap(params: Params, epoch_ids: jax.Array):
         part = block_part(epoch_ids)
 
         def round_body(carry, eids):             # eids: (K,) one merge round
             stacked, ovf = carry
-            base = jax.tree.map(lambda x: x[0], stacked)  # shared round input
+            base = _shared(stacked)              # shared round input
 
             def local_epoch(carry, e):
                 stacked, acc = carry
@@ -1278,7 +1305,7 @@ def make_block_fn(
         (stacked, ovf), losses = jax.lax.scan(
             round_body, (_broadcast(params), jnp.zeros((), jnp.int32)),
             epoch_ids.reshape(-1, K))
-        out = jax.tree.map(lambda x: x[0], stacked)
+        out = _shared(stacked)
         if with_overflow:
             return out, losses.reshape(-1), ovf
         return out, losses.reshape(-1)
@@ -1364,10 +1391,7 @@ def make_block_fn(
                 def local_epoch(carry, e):
                     local, acc = carry
                     pos, neg = worker_epoch_data(e, w, part_w)
-                    local, stats = model.run_epoch(
-                        local, pos, neg, tcfg,
-                        sparse_apply=cfg.merge_transport == "sparse",
-                        update_mask=update_mask)
+                    local, stats = run(local, pos, neg)
                     acc = jax.tree.map(jnp.add, acc, stats)
                     return (local, acc), jax.lax.pmean(stats.mean_loss, ax)
 
@@ -1428,9 +1452,7 @@ def make_block_fn(
                 def local_epoch(carry, e):
                     local, acc = carry
                     pos, neg = worker_epoch_data(e, w, part_w)
-                    local, stats = model.run_epoch(
-                        local, pos, neg, tcfg,
-                        sparse_apply=cfg.merge_transport == "sparse")
+                    local, stats = run(local, pos, neg)
                     acc = jax.tree.map(jnp.add, acc, stats)
                     return (local, acc), jax.lax.pmean(stats.mean_loss, ax)
 
@@ -2029,26 +2051,32 @@ def _train_device(
             length = min(length, repart - start % repart)
         epoch_ids = jnp.arange(start, start + length, dtype=jnp.int32)
         if with_overflow:
-            state, losses, overflow = block_fn(state, epoch_ids)
+            with obs.span("fit.block"):
+                state, losses, overflow = block_fn(state, epoch_ids)
             _raise_on_overflow(overflow, start + length - 1)
         else:
-            state, losses = block_fn(state, epoch_ids)
+            with obs.span("fit.block"):
+                state, losses = block_fn(state, epoch_ids)
         # evals/checkpoints/results read the *global view* — under
         # staleness the worker locals are divergent scratch state
         params = state[0] if stale else state
         loss_blocks.append(losses)               # device array per block
         start += length
         if callback is not None:
-            callback(start - 1, float(losses[-1]))
+            with obs.span("fit.sync"):          # waits for the block
+                last = float(losses[-1])
+            callback(start - 1, last)
         stop = False
-        if recorder is not None and (
-            start % eval_every == 0 or start == epochs
-        ):
-            stop = recorder.record(
-                start - 1, start // sched.merge_every, float(losses[-1]),
-                params)
-        if writer is not None and writer.due(start, epochs, stopping=stop):
-            writer.save(start, params, snapshot_history())
+        with obs.span("fit.boundary"):
+            if recorder is not None and (
+                start % eval_every == 0 or start == epochs
+            ):
+                stop = recorder.record(
+                    start - 1, start // sched.merge_every,
+                    float(losses[-1]), params)
+            if writer is not None and writer.due(start, epochs,
+                                                 stopping=stop):
+                writer.save(start, params, snapshot_history())
         if stop:
             epochs_run = start
             break

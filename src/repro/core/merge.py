@@ -102,6 +102,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 STRATEGIES = (
     "random",
     "average",
@@ -208,6 +210,30 @@ def merge_stacked(
 # Collective path: per-shard (N, k) inside shard_map over `axis`
 # ---------------------------------------------------------------------------
 
+# The Reduce's traffic between workers: every collective of the shard_map
+# merge goes through one of these, so its ops carry the
+# ``repro.reduce.exchange`` scope (op metadata only).
+
+@obs.scope("reduce.exchange")
+def all_gather(x: jax.Array, axis: str) -> jax.Array:
+    return jax.lax.all_gather(x, axis)
+
+
+@obs.scope("reduce.exchange")
+def psum(x: jax.Array, axis: str) -> jax.Array:
+    return jax.lax.psum(x, axis)
+
+
+@obs.scope("reduce.exchange")
+def pmax(x: jax.Array, axis: str) -> jax.Array:
+    return jax.lax.pmax(x, axis)
+
+
+@obs.scope("reduce.exchange")
+def pmin(x: jax.Array, axis: str) -> jax.Array:
+    return jax.lax.pmin(x, axis)
+
+
 def _select_by_priority_psum(
     local: jax.Array, priority: jax.Array, axis: str
 ) -> jax.Array:
@@ -221,12 +247,12 @@ def _select_by_priority_psum(
     ``argmax`` first-winner tie-break); (3) one masked psum of the winner's
     rows."""
     idx = jax.lax.axis_index(axis).astype(jnp.float32)
-    best = jax.lax.pmax(priority, axis)                           # (N,)
+    best = pmax(priority, axis)                                   # (N,)
     am_best = priority == best
     my_claim = jnp.where(am_best, idx, jnp.inf)
-    winner = jax.lax.pmin(my_claim, axis)                         # (N,)
+    winner = pmin(my_claim, axis)                                 # (N,)
     mine = (am_best & (idx == winner)).astype(local.dtype)        # (N,)
-    return jax.lax.psum(local * mine[:, None], axis)
+    return psum(local * mine[:, None], axis)
 
 
 def merge_collective(
@@ -243,16 +269,16 @@ def merge_collective(
     per-worker 0/1 scalar (this worker's own flag): dead workers are excluded
     from every strategy — the K-of-N fault-tolerant merge of DESIGN.md §4."""
     live = jnp.ones((), local.dtype) if liveness is None else liveness.astype(local.dtype)
-    W_live = jax.lax.psum(live, axis)
+    W_live = psum(live, axis)
 
     if strategy == "average_all":
-        return jax.lax.psum(local * live, axis) / jnp.maximum(W_live, 1.0)
+        return psum(local * live, axis) / jnp.maximum(W_live, 1.0)
 
     if strategy == "average":
         w = count * live                                          # (N,)
-        total = jax.lax.psum(w, axis)
-        weighted = jax.lax.psum(local * w[:, None], axis)
-        plain = jax.lax.psum(local * live, axis) / jnp.maximum(W_live, 1.0)
+        total = psum(w, axis)
+        weighted = psum(local * w[:, None], axis)
+        plain = psum(local * live, axis) / jnp.maximum(W_live, 1.0)
         return jnp.where(
             total[:, None] > 0, weighted / jnp.maximum(total, 1.0)[:, None], plain
         )
@@ -266,7 +292,7 @@ def merge_collective(
         idx = jax.lax.axis_index(axis)
         u = jax.random.uniform(jax.random.fold_in(key, idx), count.shape)
         touched = (count > 0) & (live > 0)
-        any_touch = jax.lax.psum(touched.astype(jnp.float32), axis) > 0
+        any_touch = psum(touched.astype(jnp.float32), axis) > 0
         pri = jnp.where(touched, u, jnp.where(any_touch, -_BIG, u))
         pri = jnp.where(live > 0, pri, -2 * _BIG)
         return _select_by_priority_psum(local, pri, axis)
@@ -296,10 +322,10 @@ def merge_allgather(
     """Paper-literal Reduce: gather all W copies then run the stacked merge.
     O(W·N·k) collective bytes — kept as the faithful baseline the §Perf
     hillclimb starts from."""
-    stacked = jax.lax.all_gather(local, axis)                    # (W, N, k)
-    counts = jax.lax.all_gather(count, axis)                     # (W, N)
-    losses = jax.lax.all_gather(loss, axis)
-    wl = jax.lax.all_gather(worker_loss, axis)                   # (W,)
+    stacked = all_gather(local, axis)                            # (W, N, k)
+    counts = all_gather(count, axis)                             # (W, N)
+    losses = all_gather(loss, axis)
+    wl = all_gather(worker_loss, axis)                           # (W,)
     return merge_stacked(strategy, stacked, counts, losses, wl, key)
 
 
@@ -792,8 +818,8 @@ def merge_sparse_stale_collective(
     own, rows = _merge_own_block_stale(
         strategy, idx, vals, cnts, losses, worker_loss, base,
         lo, R, cand, key)
-    owns = jax.lax.all_gather(own, axis)
-    rws = jax.lax.all_gather(rows, axis)
+    owns = all_gather(own, axis)
+    rws = all_gather(rows, axis)
     return apply_delta(base, owns.reshape(-1), rws.reshape(-1, rws.shape[-1]))
 
 
@@ -828,7 +854,7 @@ def merge_sparse_sharded_collective(
         strategy, idx, vals, cnts, losses, worker_loss, base,
         normalize_row_fn, repeats, lo, R, cand, key,
     )
-    owns = jax.lax.all_gather(own, axis)                    # (W, cap)
-    rws = jax.lax.all_gather(rows, axis)                    # (W, cap, k)
+    owns = all_gather(own, axis)                            # (W, cap)
+    rws = all_gather(rows, axis)                            # (W, cap, k)
     out = sparse_untouched_base(strategy, local, W)
     return apply_delta(out, owns.reshape(-1), rws.reshape(-1, rws.shape[-1]))

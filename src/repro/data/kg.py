@@ -58,6 +58,8 @@ class KG:
         default=None, repr=False, compare=False)
     _filter_cands: Dict = dataclasses.field(
         default_factory=dict, repr=False, compare=False)
+    _filter_counts: Dict = dataclasses.field(
+        default_factory=dict, repr=False, compare=False)
     _tc_negatives: Dict = dataclasses.field(
         default_factory=dict, repr=False, compare=False)
 
@@ -137,7 +139,20 @@ class KG:
                     "bounds.  Raise max_fanout (or leave it None) for exact "
                     "filtering.", stacklevel=2)
             self._filter_cands[max_fanout] = (tails, heads)
+            known = sum(map(len, tail_groups)) + sum(map(len, head_groups))
+            self._filter_counts[max_fanout] = (
+                tails.size + heads.size, known - dropped)
         return self._filter_cands[max_fanout]
+
+    def eval_filter_counts(
+        self, max_fanout: Optional[int] = None
+    ) -> Tuple[int, int]:
+        """``(cells, known)`` of the masks :meth:`eval_filter_candidates`
+        builds: the padded cells one filtered pass scores,
+        ``n_test × (P_tail + P_head)``, and how many of them hold a real
+        known candidate.  Counted once, when the masks are built."""
+        self.eval_filter_candidates(max_fanout)
+        return self._filter_counts[max_fanout]
 
     def known_candidate_masks(
         self, pairs: np.ndarray, side: str
@@ -210,6 +225,7 @@ class KG:
         self._known = None
         self._known_index = None
         self._filter_cands = {}
+        self._filter_counts = {}
         self._tc_negatives = {}
 
     def extend(

@@ -134,5 +134,6 @@ def rank_counts(
         out_specs=pl.BlockSpec((tb, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, 1), jnp.float32),
         interpret=interpret,
+        name="rank_counts",
     )(qp, tp, gp)
     return cnt[:B, 0].astype(jnp.int32)

@@ -13,6 +13,8 @@ from typing import Sequence, Tuple, Union
 import jax
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
+
 AxisLike = Union[None, str, Tuple[str, ...]]
 
 
@@ -58,6 +60,7 @@ def worker_map(fn, *, backend: str, mesh=None, axis_name: str = "workers"):
     return run
 
 
+@obs.scope("reduce.exchange")
 def all_gather_deltas(packed, axis_name: str):
     """All-gather a worker's packed sparse-delta buffers across the named
     shard_map axis: every leaf of the pytree (row ids, values, counts,

@@ -101,3 +101,41 @@ def test_training_block_fits_one_chip(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert 0 < total < HBM_BYTES, mem
+
+
+@pytest.mark.parametrize("program_name", ["train_block", "eval_scan"])
+def test_scopes_change_only_metadata_on_v5e(one_chip, program_name):
+    """The chip's compiler, too, makes the same program with the
+    ``repro.*`` scopes as without them (``tests/test_obs.py`` checks the
+    CPU's): the training block and the fused eval scan with its kernel,
+    at a small width."""
+    from test_obs import program, scoped_and_plain, scopes_in
+
+    E, R, k = 2048, 64, 128
+    tables = {"ent": _spec(one_chip, (E, k)), "rel": _spec(one_chip, (R, k))}
+    if program_name == "train_block":
+        empty = np.zeros((0, 3), np.int32)
+        graph = kg_lib.KG(E, R, empty, empty, empty)
+        kcfg, mcfg = kg.make_configs(graph, "transe", "sgd", dim=k,
+                                     n_workers=4, batch_size=64)
+        partitioned = np.random.default_rng(0).integers(
+            0, R, size=(4, 1024, 3)).astype(np.int32)
+
+        def compile_text():
+            block = mapreduce.make_block_fn(
+                mcfg, kcfg, partitioned, model=get_model("transe"))
+            return block.lower(tables, _spec(one_chip, (1,), jnp.int32)) \
+                .compile().as_text()
+    else:
+        ids = _spec(one_chip, (1, 4, 64, 3), jnp.int32)
+        cands = _spec(one_chip, (1, 4, 64, 8), jnp.int32)
+
+        def compile_text():
+            return eval_device._entity_ranks_device.lower(
+                get_model("transe"), tables, ids, cands, cands,
+                norm="l1", backend="vmap", mesh=None, axis_name="workers",
+                fused=True, relations=True).compile().as_text()
+
+    scoped, plain = scoped_and_plain(compile_text)
+    assert program(scoped) == program(plain)
+    assert scopes_in(scoped)
