@@ -1081,6 +1081,23 @@ def _zero_stats(tcfg: KGConfig, lead: tuple = ()) -> EpochStats:
     )
 
 
+def compact_map(cfg: MapReduceConfig, tcfg: KGConfig,
+                masked: bool = False) -> bool:
+    """Whether the device pipeline's SGD Map steps only the batch's rows
+    (``KGModel.sgd_step_sparse`` / ``run_epoch_flat``) instead of the dense
+    ``sgd_step``: when a batch's ``4B`` entity candidate slots are fewer
+    than 4/9 of the table's rows, and no per-step projection rewrites
+    every row anyway.  A candidate slot (gathered, stepped, written back)
+    costs about 2¼ rows of the dense step's passes over the table: on one
+    TPU v5e at E=14,951, k=400, W=4 the compact step wins at 4B = 0.41 E
+    and loses at 0.48 E.  The masked fine-tune (``masked``) always takes
+    it — it rides the candidate gather.  The two steps agree to the last
+    bit but where XLA sums a repeated row's contributions in another order
+    (``KGModel.sgd_step_sparse``)."""
+    return masked or (9 * cfg.batch_size < tcfg.n_entities
+                      and tcfg.normalize != "step")
+
+
 def make_block_fn(
     cfg: MapReduceConfig,
     tcfg: KGConfig,
@@ -1158,6 +1175,12 @@ def make_block_fn(
     restricted to the same mask would compute.  Requires the SGD
     paradigm's sparse transport with ``staleness == 0``.
 
+    The SGD Map's step follows :func:`compact_map`, not the transport:
+    the compact row step where a batch references fewer rows than the
+    table holds, else the dense ``sgd_step``; the vmap backend runs the
+    compact step on the W workers' tables laid end to end
+    (``KGModel.run_epoch_flat``), reshaped once per merge round.
+
     The vmap and shard_map backends derive identical per-worker keys (vmapped
     ``fold_in(·, w)`` vs ``fold_in(·, axis_index)``), so the two backends see
     the same batches and negatives."""
@@ -1179,15 +1202,16 @@ def make_block_fn(
                 f"merge_transport={cfg.merge_transport!r}, staleness={S}")
         update_mask = {name: jnp.asarray(m, dtype=bool)
                        for name, m in update_mask.items()}
+    compact = compact_map(cfg, tcfg, update_mask is not None)
     run_epoch = functools.partial(
-        model.run_epoch, cfg=tcfg,
-        sparse_apply=cfg.merge_transport == "sparse",
+        model.run_epoch, cfg=tcfg, sparse_apply=compact,
         update_mask=update_mask)
 
     @obs.scope("map")
     def run(params: Params, pos: jax.Array, neg: jax.Array):
         """One worker's local epoch of SGD steps from ``params``: the Map
-        (both backends; ``update_mask`` is None whenever staleness > 0)."""
+        (shard_map backend, and the vmap backend's dense step;
+        ``update_mask`` is None whenever staleness > 0)."""
         return run_epoch(params, pos, neg)
 
     @obs.scope("reduce")
@@ -1273,6 +1297,33 @@ def make_block_fn(
         """Worker 0's copy of every table: after a Reduce all W are equal."""
         return jax.tree.map(lambda x: x[0], stacked)
 
+    # The W workers' local tables during a merge round's epochs: the
+    # (W, N, k) stack for the dense step, or, for the compact step, the
+    # same rows laid end to end as (W * N, k) — reshaped once per round,
+    # outside the step loop (see ``KGModel.run_epoch_flat``).
+    if compact:
+        @obs.scope("map")
+        def map_epoch(local: Params, pos: jax.Array, neg: jax.Array):
+            return model.run_epoch_flat(local, pos, neg, tcfg,
+                                        update_mask=update_mask)
+
+        @obs.scope("map")
+        def to_local(stacked: Params) -> Params:
+            return jax.tree.map(
+                lambda x: x.reshape((-1,) + x.shape[2:]), stacked)
+
+        @obs.scope("map")
+        def to_stacked(local: Params) -> Params:
+            return jax.tree.map(
+                lambda x: x.reshape((W, -1) + x.shape[1:]), local)
+    else:
+        map_epoch = jax.vmap(run)
+
+        def to_local(stacked: Params) -> Params:
+            return stacked
+
+        to_stacked = to_local
+
     def sgd_block_vmap(params: Params, epoch_ids: jax.Array):
         part = block_part(epoch_ids)
 
@@ -1281,14 +1332,16 @@ def make_block_fn(
             base = _shared(stacked)              # shared round input
 
             def local_epoch(carry, e):
-                stacked, acc = carry
+                local, acc = carry
                 pos, neg = epoch_data(e, part)
-                stacked, stats = jax.vmap(run)(stacked, pos, neg)
+                local, stats = map_epoch(local, pos, neg)
                 acc = jax.tree.map(jnp.add, acc, stats)
-                return (stacked, acc), jnp.mean(stats.mean_loss)
+                return (local, acc), jnp.mean(stats.mean_loss)
 
-            (stacked, acc), losses = jax.lax.scan(
-                local_epoch, (stacked, _zero_stats(tcfg, (W,))), eids)
+            (local, acc), losses = jax.lax.scan(
+                local_epoch, (to_local(stacked), _zero_stats(tcfg, (W,))),
+                eids)
+            stacked = to_stacked(local)
             acc = dataclasses.replace(acc, mean_loss=acc.mean_loss / K)
             mk = jax.random.fold_in(k_merge, eids[-1])
             if cfg.merge_transport == "sparse":
@@ -1342,14 +1395,16 @@ def make_block_fn(
             stacked = jax.tree.map(adopt, g, local)
 
             def local_epoch(carry, e):
-                stacked, acc = carry
+                local, acc = carry
                 pos, neg = epoch_data(e, part)
-                stacked, stats = jax.vmap(run)(stacked, pos, neg)
+                local, stats = map_epoch(local, pos, neg)
                 acc = jax.tree.map(jnp.add, acc, stats)
-                return (stacked, acc), jnp.mean(stats.mean_loss)
+                return (local, acc), jnp.mean(stats.mean_loss)
 
-            (stacked, acc), losses = jax.lax.scan(
-                local_epoch, (stacked, _zero_stats(tcfg, (W,))), eids)
+            (local, acc), losses = jax.lax.scan(
+                local_epoch, (to_local(stacked), _zero_stats(tcfg, (W,))),
+                eids)
+            stacked = to_stacked(local)
             acc = dataclasses.replace(acc, mean_loss=acc.mean_loss / K)
             mk = jax.random.fold_in(k_merge, eids[-1])
             if cfg.merge_transport == "sparse":
@@ -2003,6 +2058,15 @@ def _train_device(
         seed=seed, donate=donate, with_overflow=with_overflow,
         strata=strata, update_mask=update_mask)
 
+    # which SGD step the Map runs, counted once per block on the host
+    map_counter = None
+    if cfg.paradigm == "sgd":
+        map_counter = ("map.compact_steps"
+                       if compact_map(cfg, tcfg, update_mask is not None)
+                       else "map.dense_steps")
+        steps_per_epoch = cfg.n_workers * (
+            partitioned.shape[1] // cfg.batch_size)
+
     # bounded staleness threads (global_view, worker_locals) through the
     # blocks — locals must survive block boundaries or slicing at eval/
     # checkpoint points would change results.  Locals start as W copies of
@@ -2057,6 +2121,8 @@ def _train_device(
         else:
             with obs.span("fit.block"):
                 state, losses = block_fn(state, epoch_ids)
+        if map_counter is not None:
+            obs.count(map_counter, steps_per_epoch * length)
         # evals/checkpoints/results read the *global view* — under
         # staleness the worker locals are divergent scratch state
         params = state[0] if stale else state

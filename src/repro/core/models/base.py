@@ -107,6 +107,23 @@ def apply_gradients(params: Params, grads: Params, lr: float) -> Params:
     return jax.tree.map(lambda p, g: p - lr * g, params, grads)
 
 
+def _distinct(
+    ids: jax.Array, n_rows: int, size: int
+) -> tuple[jax.Array, jax.Array]:
+    """``jnp.unique(ids, size=size, fill_value=n_rows,
+    return_inverse=True)`` for ids in ``[0, n_rows)``, by three sorts:
+    the distinct ids ascending, padded with ``n_rows``, and each id's slot
+    among them.  ``jnp.unique`` builds both with scatters, which the TPU
+    runs an element at a time."""
+    s, order = jax.lax.sort(
+        (ids, jnp.arange(ids.shape[0], dtype=ids.dtype)), num_keys=1)
+    first = jnp.concatenate([jnp.ones((1,), bool), s[1:] != s[:-1]])
+    slot_sorted = jnp.cumsum(first.astype(ids.dtype)) - 1
+    distinct = jax.lax.sort(jnp.where(first, s, n_rows))[:size]
+    _, slot = jax.lax.sort((order, slot_sorted), num_keys=1)
+    return distinct, slot
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class EpochStats:
@@ -132,6 +149,17 @@ def _accumulate_touch(
     rel_count = rel_count.at[pos[:, 1]].add(1.0)
     rel_loss = rel_loss.at[pos[:, 1]].add(pair_loss)
     return ent_count, ent_loss, rel_count, rel_loss
+
+
+def _zero_touch(cfg: KGConfig, lead: tuple = ()) -> tuple:
+    """Empty touch stats for :func:`_accumulate_touch` (``lead`` workers)."""
+    E, R = cfg.n_entities, cfg.n_relations
+    return tuple(jnp.zeros(lead + (n,), cfg.dtype) for n in (E, E, R, R))
+
+
+def _epoch_stats(stats: tuple, loss_sum: jax.Array, n_steps: int) -> EpochStats:
+    return EpochStats(mean_loss=loss_sum / n_steps, ent_count=stats[0],
+                      ent_loss=stats[1], rel_count=stats[2], rel_loss=stats[3])
 
 
 class KGModel:
@@ -444,85 +472,94 @@ class KGModel:
             params = self.normalize(params)
         return params, loss
 
-    def _compact_batch(
-        self, params: Params, pos: jax.Array, neg: jax.Array, cfg: KGConfig
-    ) -> tuple[dict, Params, jax.Array, jax.Array]:
-        """Candidate row sets + compact tables + remapped triplets for one
-        batch: every row the batch references, deduplicated, with static
-        capacity (4B entity / 2B relation slots, padded with the
-        out-of-range id ``n_rows`` so scatters drop them)."""
-        ent_ids = jnp.concatenate([pos[:, 0], pos[:, 2], neg[:, 0], neg[:, 2]])
-        rel_ids = jnp.concatenate([pos[:, 1], neg[:, 1]])
+    def _candidates(
+        self, pos: jax.Array, neg: jax.Array, cfg: KGConfig
+    ) -> tuple[dict, jax.Array, jax.Array]:
+        """Every row one batch references, per role — deduplicated, sorted,
+        with static capacity (4B entity / 2B relation slots, capped at the
+        table) and padded with the out-of-range id ``n_rows`` so scatters
+        drop them — and the batch's triplets rewritten as slots of those
+        row sets."""
+        B = pos.shape[0]
         E, R = cfg.n_entities, cfg.n_relations
-        cand = {
-            "ent": jnp.unique(ent_ids, size=int(min(E, ent_ids.shape[0])),
-                              fill_value=E),
-            "rel": jnp.unique(rel_ids, size=int(min(R, rel_ids.shape[0])),
-                              fill_value=R),
-        }
+        ent, ent_slot = _distinct(
+            jnp.concatenate([pos[:, 0], pos[:, 2], neg[:, 0], neg[:, 2]]),
+            E, int(min(E, 4 * B)))
+        rel, rel_slot = _distinct(
+            jnp.concatenate([pos[:, 1], neg[:, 1]]), R, int(min(R, 2 * B)))
+        ent_slot = ent_slot.reshape(4, B).astype(pos.dtype)
+        rel_slot = rel_slot.reshape(2, B).astype(pos.dtype)
+        pos_c = jnp.stack([ent_slot[0], rel_slot[0], ent_slot[1]], axis=1)
+        neg_c = jnp.stack([ent_slot[2], rel_slot[1], ent_slot[3]], axis=1)
+        return {"ent": ent, "rel": rel}, pos_c, neg_c
+
+    def _gather_rows(self, params: Params, cand: dict) -> Params:
+        """The compact tables: each table's candidate rows (zeros for
+        padding slots)."""
         roles = self.param_roles()
-        compact = {
+        return {
             name: jnp.take(params[name], cand[roles[name]], axis=0,
                            mode="fill", fill_value=0.0)
             for name in params
         }
 
-        def remap(t):
-            return jnp.stack([
-                jnp.searchsorted(cand["ent"], t[:, 0]),
-                jnp.searchsorted(cand["rel"], t[:, 1]),
-                jnp.searchsorted(cand["ent"], t[:, 2]),
-            ], axis=1).astype(t.dtype)
-
-        return cand, compact, remap(pos), remap(neg)
+    def _compact_update(
+        self, compact: Params, cand: dict, pos_c: jax.Array,
+        neg_c: jax.Array, cfg: KGConfig, update_mask: Params | None = None,
+    ) -> tuple[Params, jax.Array]:
+        """The SGD step on one batch's compact tables (rows ``cand``,
+        triplets remapped to slots): the stepped rows and the loss.  Frozen
+        rows of ``update_mask`` keep their compact value."""
+        # the remap preserves id (in)equality — every pos and neg id has
+        # its own slot in the candidate list — so the joint objective's
+        # side/candidate/gold-mask derivation computes the same booleans on
+        # the compact triplets as on the originals
+        loss, grads = jax.value_and_grad(self._loss_fn(cfg))(
+            compact, pos_c, neg_c)
+        stepped = {
+            name: compact[name] - cfg.learning_rate * grads[name]
+            for name in compact
+        }
+        if update_mask is not None:
+            roles = self.param_roles()
+            stepped = {
+                name: jnp.where(
+                    jnp.take(update_mask[name], cand[roles[name]],
+                             mode="fill", fill_value=False)[:, None],
+                    stepped[name], compact[name])
+                for name in compact
+            }
+        return stepped, loss
 
     def sgd_step_sparse(
         self, params: Params, pos: jax.Array, neg: jax.Array, cfg: KGConfig,
         update_mask: Params | None = None,
     ) -> tuple[Params, jax.Array]:
         """:meth:`sgd_step` touching only the rows the batch references —
-        the ParaGraphE idiom, and the Map-phase half of the sparse
-        transport (``merge_transport="sparse"``): per step the tables see
-        one O(batch) gather and one O(batch) scatter instead of a
-        table-sized gradient materialization.
+        the ParaGraphE idiom: per step the tables see one O(batch) gather
+        and one O(batch) scatter instead of a table-sized gradient
+        materialization.
 
-        Bitwise-identical to the dense step: the energy evaluated on the
-        gathered compact tables computes the same floats (gathers
-        compose), its gradient is the same per-row scatter-add of the same
-        cotangents in the same update order (just into compact buffers),
-        and a row no batch id references has gradient exactly ``+0.0``
-        under the dense step (``p - lr*0 == p`` bitwise), so skipping it
-        changes nothing.  tests/test_sparse_transport.py pins the
-        equivalence across models, strategies, and pipelines.
+        The dense step's arithmetic: the energy evaluated on the gathered
+        compact tables computes the same floats (gathers compose), its
+        gradient sums the same per-row cotangents (just into compact
+        buffers), and a row no batch id references has gradient exactly
+        ``+0.0`` under the dense step (``p - lr*0 == p`` bitwise), so
+        skipping it changes nothing.  Only the order in which XLA sums a
+        repeated row's contributions may differ between the two programs,
+        in the last bit of that row.  tests/test_sparse_transport.py pins
+        the two bitwise where the order agrees, and within a few ulps where
+        the candidate set is smaller than the table.
 
         ``update_mask`` (the online tier's masked fine-tune) freezes every
         row whose mask bit is False: a frozen candidate row scatters its
         *unchanged* compact value back (a bitwise no-op), while free rows
         step normally against the pristine frozen values."""
-        cand, compact, pos_c, neg_c = self._compact_batch(
-            params, pos, neg, cfg)
-        # the remap preserves id (in)equality — both pos and neg ids appear
-        # in the candidate list and searchsorted maps them injectively — so
-        # the joint objective's side/candidate/gold-mask derivation computes
-        # the same booleans on the compact triplets as on the originals
-        loss, grads = jax.value_and_grad(self._loss_fn(cfg))(
-            compact, pos_c, neg_c)
+        cand, pos_c, neg_c = self._candidates(pos, neg, cfg)
         roles = self.param_roles()
-        stepped = {
-            name: compact[name] - cfg.learning_rate * grads[name]
-            for name in params
-        }
-        if update_mask is not None:
-            free = {
-                name: jnp.take(update_mask[name], cand[roles[name]],
-                               mode="fill", fill_value=False)
-                for name in params
-            }
-            stepped = {
-                name: jnp.where(free[name][:, None], stepped[name],
-                                compact[name])
-                for name in params
-            }
+        compact = self._gather_rows(params, cand)
+        stepped, loss = self._compact_update(
+            compact, cand, pos_c, neg_c, cfg, update_mask)
         params = {
             name: params[name].at[cand[roles[name]]].set(
                 stepped[name], mode="drop")
@@ -560,52 +597,122 @@ class KGModel:
         scan SGD over the worker's minibatches, tracking the per-key stats
         Reduce needs.  Pure; used by the vmap backend (vmapped over workers)
         and inside shard_map (per shard).  ``sparse_apply`` swaps the step
-        for the bitwise-identical compact-row :meth:`sgd_step_sparse`
-        (engaged by ``merge_transport="sparse"``).  ``update_mask`` (one
-        bool row-mask per param table) freezes unmasked rows bitwise — the
-        online tier's incremental fine-tune; it requires the sparse step."""
+        for the compact-row one (:meth:`run_epoch_flat` with one worker;
+        ``mapreduce.compact_map`` decides it on the device pipeline, the
+        host pipeline engages it with ``merge_transport="sparse"``).
+        ``update_mask`` (one bool row-mask per param table) freezes
+        unmasked rows bitwise — the online tier's incremental fine-tune; it
+        requires the sparse step."""
         if update_mask is not None and not sparse_apply:
             raise ValueError(
                 "update_mask requires sparse_apply=True — the masked "
                 "fine-tune rides the compact-row step's candidate gather")
-        if update_mask is not None:
-            step = functools.partial(
-                self.sgd_step_sparse, update_mask=update_mask)
-        else:
-            step = self.sgd_step_sparse if sparse_apply else self.sgd_step
+        if sparse_apply:
+            params, stats = self.run_epoch_flat(
+                params, pos_batches[None], neg_batches[None], cfg,
+                update_mask=update_mask)
+            return params, jax.tree.map(lambda x: x[0], stats)
         pair_fn = self._pair_loss_fn(cfg)
         if cfg.normalize == "epoch":
-            params = self._masked_normalize(params, update_mask)
-        E, R = cfg.n_entities, cfg.n_relations
-        zeros = (
-            jnp.zeros((E,), cfg.dtype),
-            jnp.zeros((E,), cfg.dtype),
-            jnp.zeros((R,), cfg.dtype),
-            jnp.zeros((R,), cfg.dtype),
-        )
+            params = self.normalize(params)
 
         def body(carry, batch):
             params, stats, loss_sum = carry
             pos, neg = batch
             pair = pair_fn(params, pos, neg)
-            params, loss = step(params, pos, neg, cfg)
-            stats = _accumulate_touch(stats, pos, neg, pair, E, R)
+            params, loss = self.sgd_step(params, pos, neg, cfg)
+            stats = _accumulate_touch(stats, pos, neg, pair,
+                                      cfg.n_entities, cfg.n_relations)
             return (params, stats, loss_sum + loss), None
 
         (params, stats, loss_sum), _ = jax.lax.scan(
-            body,
-            (params, zeros, jnp.zeros((), cfg.dtype)),
-            (pos_batches, neg_batches),
-        )
-        n_steps = pos_batches.shape[0]
-        epoch_stats = EpochStats(
-            mean_loss=loss_sum / n_steps,
-            ent_count=stats[0],
-            ent_loss=stats[1],
-            rel_count=stats[2],
-            rel_loss=stats[3],
-        )
-        return params, epoch_stats
+            body, (params, _zero_touch(cfg), jnp.zeros((), cfg.dtype)),
+            (pos_batches, neg_batches))
+        return params, _epoch_stats(stats, loss_sum, pos_batches.shape[0])
+
+    def run_epoch_flat(
+        self,
+        flat: Params,               # (W * N, k) per table: W workers' rows
+        pos_batches: jax.Array,     # (W, S, B, 3)
+        neg_batches: jax.Array,     # (W, S, B, 3)
+        cfg: KGConfig,
+        update_mask: Params | None = None,
+    ) -> tuple[Params, EpochStats]:
+        """:meth:`run_epoch` with ``sparse_apply`` for W workers at once,
+        on their tables laid end to end: worker ``w``'s row ``i`` is flat
+        row ``w * N + i``.  Each step takes every worker's candidate rows
+        in one gather (ids offset by ``w * N``), steps the compact
+        ``(W, 4B, k)`` / ``(W, 2B, k)`` buffers per worker, and writes them
+        back with one scatter; padding slots point past the flat table and
+        drop.  No step touches a row outside its batch, and the flat carry
+        keeps XLA from flattening a ``(W, N, k)`` table around each batched
+        scatter.  Per worker the arithmetic is :meth:`sgd_step_sparse`'s;
+        the pair losses for the touch stats come from the same compact
+        tables (gathers compose, so they are the dense step's floats).
+        Returns the flat tables and ``(W,)``-stacked stats; with ``W = 1``
+        the flat table is the worker's own, which is how :meth:`run_epoch`
+        runs its compact step."""
+        W = pos_batches.shape[0]
+        roles = self.param_roles()
+        E, R = cfg.n_entities, cfg.n_relations
+        n_rows = {"ent": E, "rel": R}
+        pair_fn = self._pair_loss_fn(cfg)
+
+        def project(flat):
+            # the constraint projection per worker, on the (W, N, k) view
+            view = {name: x.reshape((W, -1) + x.shape[1:])
+                    for name, x in flat.items()}
+            view = jax.vmap(
+                lambda p: self._masked_normalize(p, update_mask))(view)
+            return {name: x.reshape(flat[name].shape)
+                    for name, x in view.items()}
+
+        def worker(compact, cand, pos_c, neg_c):
+            pair = pair_fn(compact, pos_c, neg_c)
+            stepped, loss = self._compact_update(
+                compact, cand, pos_c, neg_c, cfg, update_mask)
+            return stepped, loss, pair
+
+        def body(carry, batch):
+            flat, stats, loss_sum = carry
+            pos, neg = batch                                 # (W, B, 3)
+            cand, pos_c, neg_c = jax.vmap(
+                lambda p, q: self._candidates(p, q, cfg))(pos, neg)
+            rows = {
+                role: jnp.where(
+                    c < n_rows[role],
+                    c + n_rows[role] * jnp.arange(W, dtype=c.dtype)[:, None],
+                    W * n_rows[role]).reshape(-1)
+                for role, c in cand.items()
+            }
+            compact = {
+                name: jnp.take(x, rows[roles[name]], axis=0, mode="fill",
+                               fill_value=0.0).reshape(
+                                   (W, -1) + x.shape[1:])
+                for name, x in flat.items()
+            }
+            stepped, loss, pair = jax.vmap(worker)(compact, cand, pos_c,
+                                                   neg_c)
+            flat = {
+                name: x.at[rows[roles[name]]].set(
+                    stepped[name].reshape((-1,) + x.shape[1:]),
+                    mode="drop")
+                for name, x in flat.items()
+            }
+            if cfg.normalize == "step":
+                flat = project(flat)
+            stats = jax.vmap(
+                lambda s, p, q, l: _accumulate_touch(s, p, q, l, E, R))(
+                    stats, pos, neg, pair)
+            return (flat, stats, loss_sum + loss), None
+
+        if cfg.normalize == "epoch":
+            flat = project(flat)
+        (flat, stats, loss_sum), _ = jax.lax.scan(
+            body, (flat, _zero_touch(cfg, (W,)), jnp.zeros((W,), cfg.dtype)),
+            (jnp.swapaxes(pos_batches, 0, 1),
+             jnp.swapaxes(neg_batches, 0, 1)))
+        return flat, _epoch_stats(stats, loss_sum, pos_batches.shape[1])
 
     def batch_gradients(
         self, params: Params, pos: jax.Array, neg: jax.Array, cfg: KGConfig

@@ -1,6 +1,7 @@
 """``repro.obs``: the scopes change op metadata and nothing else, every
-scope reaches the compiled program, the spans reach a profiler trace, and
-the filter counters equal the mask arithmetic.
+scope reaches the compiled program, the spans reach a profiler trace, the
+filter counters equal the mask arithmetic, and the Map-step counters count
+each block's steps.
 
 "Nothing else" is checked on compiled HLO text: with the metadata, the
 stack-frame tables and the numbers that make instruction names unique
@@ -85,10 +86,9 @@ def graph():
                                n_triplets=800)
 
 
-@pytest.mark.parametrize("transport", ["dense", "sparse"])
-def test_train_block_scopes_change_only_metadata(graph, transport):
+def _check_train_block_scopes(graph, transport, batch_size):
     kcfg, mcfg = kg_api.make_configs(
-        graph, "transe", "sgd", dim=8, n_workers=2, batch_size=16,
+        graph, "transe", "sgd", dim=8, n_workers=2, batch_size=batch_size,
         merge_transport=transport)
     model = get_model("transe")
     part = graph.train[:len(graph.train) // 2 * 2].reshape(2, -1, 3)
@@ -105,6 +105,17 @@ def test_train_block_scopes_change_only_metadata(graph, transport):
     assert program(scoped) == program(plain)
     assert scopes_in(scoped) == {"repro.map", "repro.negatives",
                                  "repro.reduce"}
+
+
+@pytest.mark.parametrize("transport", ["dense", "sparse"])
+def test_train_block_scopes_change_only_metadata(graph, transport):
+    _check_train_block_scopes(graph, transport, 16)
+
+
+@pytest.mark.parametrize("transport", ["dense", "sparse"])
+def test_compact_map_block_scopes_change_only_metadata(graph, transport):
+    """The same at batch 4, where the Map steps the flat worker tables."""
+    _check_train_block_scopes(graph, transport, 4)
 
 
 def test_shard_map_block_scopes_change_only_metadata():
@@ -209,6 +220,21 @@ def test_counters_equal_mask_arithmetic(graph):
     assert obs.counters()["eval.filter_cells"] == 2 * cells
     obs.reset()
     assert obs.counters() == {}
+
+
+@pytest.mark.parametrize("batch_size,step", [(16, "dense"), (4, "compact")])
+def test_map_step_counted_once_per_block(graph, batch_size, step):
+    """``map.compact_steps`` / ``map.dense_steps``: W × steps × epochs of
+    the Map step the device pipeline ran (compact where 9B < E: 36 < 64
+    at batch 4), counted on the host once per block."""
+    n_w = len(graph.train) // 2
+    obs.reset()
+    for fits in (1, 2):
+        kg_api.fit(graph, "transe", "sgd", epochs=2, dim=8, n_workers=2,
+                   batch_size=batch_size, pipeline="device", block_epochs=1)
+        assert obs.counters() == {
+            f"map.{step}_steps": fits * 2 * (n_w // batch_size) * 2}
+    obs.reset()
 
 
 def test_counters_follow_max_fanout(graph):

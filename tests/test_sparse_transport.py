@@ -27,8 +27,10 @@ except ImportError:
     HAVE_HYPOTHESIS = False
 
 from repro import kg as kg_api
+from repro import obs
+from repro.core import mapreduce
 from repro.core import merge as merge_lib
-from repro.core.models import get_model
+from repro.core.models import KGConfig, get_model
 from repro.data import kg as kg_lib
 
 MODELS = ["transe", "transh", "distmult"]
@@ -250,6 +252,127 @@ def test_compact_step_bitwise(model_name, normalize):
         np.testing.assert_array_equal(
             np.asarray(dense_p[k]), np.asarray(sparse_p[k]),
             err_msg=f"table {k}")
+
+
+# Where the candidate set is smaller than the table, XLA may sum a repeated
+# row's gradient contributions in another order in the compact program than
+# in the dense one: the two agree to the last bit but for a few rows, which
+# differ by a few ulps of the table's unit scale.
+TABLE_RTOL, TABLE_ATOL = 1e-6, 1e-7
+
+
+def _assert_close_tables(compact, dense):
+    """Losses bitwise, tables within ``TABLE_RTOL``/``TABLE_ATOL``."""
+    np.testing.assert_array_equal(np.asarray(compact[1]),
+                                  np.asarray(dense[1]))
+    assert set(compact[0]) == set(dense[0])
+    for k in dense[0]:
+        np.testing.assert_allclose(
+            np.asarray(compact[0][k]), np.asarray(dense[0][k]),
+            rtol=TABLE_RTOL, atol=TABLE_ATOL, err_msg=f"table {k}")
+
+
+@pytest.mark.parametrize("model_name", MODELS)
+def test_compact_step_close_where_candidates_fewer(model_name):
+    """``sgd_step_sparse`` against the plain full-table gradient step on
+    batches whose 4B candidate slots are fewer than the table's rows (so
+    the compact tables are a proper subset, with repeated rows): 200
+    steps, each from the dense step's last tables."""
+    model = get_model(model_name)
+    E, R, B = 600, 12, 30
+    kcfg = KGConfig(n_entities=E, n_relations=R, dim=8, learning_rate=0.05)
+    rng = np.random.default_rng(11)
+    params = model.init_params(jax.random.PRNGKey(0), kcfg)
+    dense_step = jax.jit(model.sgd_step, static_argnums=3)
+    sparse_step = jax.jit(model.sgd_step_sparse, static_argnums=3)
+    for _ in range(200):
+        pos = _random_batch(rng, E, R, B)
+        neg = _random_batch(rng, E, R, B)
+        dense = dense_step(params, pos, neg, kcfg)
+        _assert_close_tables(sparse_step(params, pos, neg, kcfg), dense)
+        params = dense[0]
+
+
+# ---------------------------------------------------------------------------
+# The device pipeline's compact Map on flat worker tables
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def wide_kg():
+    # 3600 train triples, 900 per worker at W=4: batch 30 gives 30 exact
+    # steps, and 9 × 30 < 600: the Map takes the compact step
+    return kg_lib.synthetic_kg(0, n_entities=600, n_relations=12,
+                               n_triplets=4000)
+
+
+def _wide_fit(graph, **kw):
+    # miniloss_perkey merges by the per-row losses, so the touch stats'
+    # pair losses count as well as the tables
+    return _fit(graph, n_workers=4, batch_size=30, pipeline="device",
+                strategy="miniloss_perkey", **kw)
+
+
+def _assert_fits_close(compact, dense):
+    _assert_close_tables((compact.params, compact.loss_history),
+                         (dense.params, dense.loss_history))
+
+
+@pytest.mark.parametrize("merge_every", [1, 2])
+@pytest.mark.parametrize("transport", ["dense", "sparse"])
+@pytest.mark.parametrize("normalize", ["epoch", "none"])
+@pytest.mark.parametrize("model", MODELS)
+def test_flat_compact_map_matches_dense_step(wide_kg, monkeypatch, model,
+                                             normalize, transport,
+                                             merge_every):
+    """Where ``9B < E`` the vmap backend's Map steps only the batch's rows
+    on the W workers' tables laid end to end
+    (``KGModel.run_epoch_flat``); after 3 merge rounds its losses equal,
+    bitwise, and its tables within a few ulps, those of the same fit with
+    every worker on the plain full-table ``sgd_step`` (the dispatch rule
+    patched off)."""
+    kw = dict(model=model, normalize=normalize, merge_transport=transport,
+              merge_every=merge_every, block_epochs=merge_every,
+              epochs=3 * merge_every)
+    compact = _wide_fit(wide_kg, **kw)
+    with monkeypatch.context() as m:
+        m.setattr(mapreduce, "compact_map", lambda *a, **k: False)
+        dense = _wide_fit(wide_kg, **kw)
+    _assert_fits_close(compact, dense)
+
+
+@pytest.mark.parametrize("transport", ["dense", "sparse"])
+def test_flat_compact_map_matches_dense_step_stale(wide_kg, monkeypatch,
+                                                   transport):
+    """The same under bounded staleness, whose worker tables persist
+    across merge rounds and blocks."""
+    kw = dict(merge_transport=transport, staleness=1, epochs=4,
+              block_epochs=2)
+    compact = _wide_fit(wide_kg, **kw)
+    with monkeypatch.context() as m:
+        m.setattr(mapreduce, "compact_map", lambda *a, **k: False)
+        dense = _wide_fit(wide_kg, **kw)
+    _assert_fits_close(compact, dense)
+
+
+@pytest.mark.parametrize("normalize,batch_size,step", [
+    ("epoch", 30, "compact"),
+    ("none", 30, "compact"),
+    ("step", 30, "dense"),         # every step projects every row anyway
+    ("epoch", 100, "dense"),       # 4B < E <= 9B: slots cost more than rows
+    ("epoch", 150, "dense"),       # 4B >= E: no row fewer to step
+])
+def test_map_step_follows_dispatch_rule(wide_kg, normalize, batch_size,
+                                        step):
+    """The device pipeline picks the Map step from what it can see —
+    ``9B < E`` and no per-step projection — whatever the Reduce's wire
+    format; the ``map.*_steps`` counters say which ran, W × steps ×
+    epochs of it, counted once per block."""
+    obs.reset()
+    _fit(wide_kg, n_workers=4, batch_size=batch_size, pipeline="device",
+         normalize=normalize, merge_transport="sparse", epochs=3)
+    steps = 4 * (900 // batch_size) * 3
+    assert obs.counters() == {f"map.{step}_steps": steps}
+    obs.reset()
 
 
 @pytest.mark.parametrize("model_name", MODELS)

@@ -10,6 +10,8 @@ Shapes are ``chip_smoke.py``'s deployment: FB15k's entity and relation
 counts at dim 400.  The topology is described inside a fixture and never
 at import time: only one process at a time may load the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -81,26 +83,110 @@ def test_default_eval_program_holds_kernel(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_training_block_fits_one_chip(one_chip):
-    """One device-pipeline epoch block at the smoke's configuration
-    compiles and needs less than one chip's memory."""
+def _training_block(sharding, **fit_kw):
+    """The smoke's one-epoch device-pipeline block, compiled, and its
+    worker count."""
     empty = np.zeros((0, 3), np.int32)
     graph = kg_lib.KG(smoke.N_ENTITIES, smoke.N_RELATIONS, empty, empty,
                       empty)
-    fit_kw = {n: v for n, v in smoke.FIT.items()
-              if n not in ("model", "paradigm")}
+    fit_kw = {**{n: v for n, v in smoke.FIT.items()
+                 if n not in ("model", "paradigm")}, **fit_kw}
     kcfg, mcfg = kg.make_configs(graph, "transe", "sgd", **fit_kw)
     W = mcfg.n_workers
     partitioned = np.random.default_rng(0).integers(
         0, smoke.N_ENTITIES, size=(W, smoke.N_TRAIN // W, 3)).astype(np.int32)
     block = mapreduce.make_block_fn(
-        mcfg, kcfg, partitioned, model=get_model("transe"), donate=True)
-    compiled = block.lower(_tables(one_chip),
-                           _spec(one_chip, (1,), jnp.int32)).compile()
+        mcfg, kcfg, partitioned, model=get_model("transe"), donate=True,
+        with_overflow=mcfg.merge_transport == "sparse")
+    compiled = block.lower(_tables(sharding),
+                           _spec(sharding, (1,), jnp.int32)).compile()
+    return compiled, W
+
+
+def test_training_block_fits_one_chip(one_chip):
+    """One device-pipeline epoch block at the smoke's configuration
+    compiles and needs less than one chip's memory."""
+    compiled, _ = _training_block(one_chip)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert 0 < total < HBM_BYTES, mem
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
+_INSTRUCTION = re.compile(
+    r"^(?:ROOT )?%([\w.\-]+) = (.*?) ([a-z][\w\-]*)\((.*)$")
+_ARRAY = re.compile(r"\b[a-z]\w*\[([\d,]*)\]")
+# ops that name or regroup a buffer without reading or writing it
+_NO_DATA = {"parameter", "get-tuple-element", "tuple", "bitcast", "while"}
+
+
+def _array_sizes(shape: str) -> list:
+    return [int(np.prod([int(d) for d in dims.split(",") if d]))
+            for dims in _ARRAY.findall(shape)]
+
+
+def _step_loop_table_ops(text: str, n_elems: int) -> tuple[int, list]:
+    """In compiled HLO ``text``: the innermost ``while`` loops that carry
+    an array of ``n_elems`` elements (the SGD step loop, which carries the
+    worker tables), and the ops of their bodies that produce an array of
+    that size, other than buffer bookkeeping and an in-place row scatter
+    (a fusion whose root scatters into its own parameter)."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None and line.startswith("  "):
+            m = _INSTRUCTION.match(line.strip())
+            if m:
+                cur.append(m.groups())
+    loops = {re.search(r"body=%([\w.\-]+)", rest).group(1)
+             for body in comps.values()
+             for _, shape, op, rest in body
+             if op == "while" and n_elems in _array_sizes(shape)}
+
+    def calls(comp, seen):
+        for _, _, _, rest in comps.get(comp, ()):
+            for callee in re.findall(r"(?:calls|body|condition)=%([\w.\-]+)",
+                                     rest):
+                if callee not in seen:
+                    seen.add(callee)
+                    calls(callee, seen)
+        return seen
+
+    innermost = [b for b in loops if not loops & calls(b, set())]
+
+    def in_place_scatter(fusion_rest):
+        callee = re.search(r"calls=%([\w.\-]+)", fusion_rest)
+        body = comps.get(callee.group(1), []) if callee else []
+        ops = {name: (op, rest) for name, _, op, rest in body}
+        root = body[-1] if body else None
+        if root is None or root[2] not in ("scatter", "dynamic-update-slice"):
+            return False
+        operand = re.match(r"\s*%([\w.\-]+)", root[3]).group(1)
+        return ops.get(operand, ("",))[0] == "parameter"
+
+    bad = [f"{name} = {shape} {op}"
+           for loop in innermost for name, shape, op, rest in comps[loop]
+           if n_elems in _array_sizes(shape) and op not in _NO_DATA
+           and not (op == "fusion" and in_place_scatter(rest))]
+    return len(innermost), bad
+
+
+@pytest.mark.parametrize("transport", ["dense", "sparse"])
+def test_train_step_loop_rewrites_no_table(one_chip, transport):
+    """At the smoke's shape a batch references at most 4 × 256 of the
+    14,951 entity rows, so the Map steps those rows alone, on the W
+    workers' tables carried flat: inside the SGD step loop no op produces
+    an array the size of the W worker tables (W·E·k elements) except the
+    in-place row scatter — no layout copy, reshape, zeroed gradient or
+    update of every row per step.  Either Reduce transport."""
+    compiled, W = _training_block(one_chip, merge_transport=transport)
+    n_loops, bad = _step_loop_table_ops(
+        compiled.as_text(), W * smoke.N_ENTITIES * smoke.FIT["dim"])
+    assert n_loops == 1
+    assert not bad, bad
 
 
 @pytest.mark.parametrize("program_name", ["train_block", "eval_scan"])
