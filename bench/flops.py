@@ -3,21 +3,20 @@ shapes (rows, entities, relations, unpadded width) and never from a
 kernel's tiles, padding or chunking, so a retiled kernel is read against
 the same work.
 
-An elementwise add, subtract, multiply, absolute value or comparison is
-one operation; a sum over ``k`` terms is ``k`` operations.
+An elementwise add, subtract, multiply, divide, absolute value or
+comparison is one operation; a sum over ``k`` terms is ``k`` operations.
+What one energy, one scanned candidate and one scanned relation cost is
+the scoring model's own count (``bench/models/<model>.py``).
 """
 from __future__ import annotations
 
+from bench import harness
+
 
 def energy_ops(model: str, dim: int) -> int:
-    """One triple's energy: TransE-L1 ``sum |h + r - t|`` (add, subtract,
-    abs, sum: 4 per column); DistMult ``-sum h r t`` (two multiplies and
-    the sum: 3 per column)."""
-    if model == "transe":
-        return 4 * dim
-    if model == "distmult":
-        return 3 * dim
-    raise ValueError(f"no operation count for model {model!r}")
+    """One triple's energy, by the model's file (TransE-L1 ``sum |h + r -
+    t|``: 4 per column; DistMult ``-sum h r t``: 3 per column)."""
+    return harness.model(model).energy_ops(dim)
 
 
 def train_ops_per_triple(model: str, dim: int) -> int:
@@ -28,10 +27,18 @@ def train_ops_per_triple(model: str, dim: int) -> int:
 
 
 def scan_ops(model: str, dim: int, rows: int, candidates: int) -> int:
-    """Scoring ``candidates`` against ``rows`` prepared queries: TransE-L1
-    subtract, abs and sum (3 per column); DistMult a multiply-add (2)."""
-    per = {"transe": 3, "distmult": 2}[model]
-    return per * dim * rows * candidates
+    """Scoring ``candidates`` entities against ``rows`` prepared queries,
+    at the model's cost of one (query, candidate) pair (TransE-L1
+    subtract, abs and sum: 3 per column; DistMult a multiply-add: 2)."""
+    return harness.model(model).candidate_ops(dim) * rows * candidates
+
+
+def relation_scan_ops(model: str, dim: int, rows: int,
+                      relations: int) -> int:
+    """Scoring every one of ``relations`` relations between the head and
+    tail of ``rows`` queries, at the model's cost of one (query, relation)
+    pair."""
+    return harness.model(model).relation_ops(dim) * rows * relations
 
 
 def eval_ops_per_test_triple(model: str, dim: int, n_entities: int,
@@ -40,7 +47,8 @@ def eval_ops_per_test_triple(model: str, dim: int, n_entities: int,
     """The paper's three tasks per test triple: both sides of the entity
     scan, the relation scan, and triple classification's four energies
     (valid and test, true and corrupted) spread over the test triples."""
-    scans = scan_ops(model, dim, 1, 2 * n_entities + n_relations)
+    scans = (scan_ops(model, dim, 1, 2 * n_entities)
+             + relation_scan_ops(model, dim, 1, n_relations))
     classify = 2 * (n_valid + n_test) * energy_ops(model, dim) / n_test
     return scans + classify
 

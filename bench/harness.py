@@ -6,11 +6,14 @@ A cell's traffic mix names its ``entry`` (``fit``, ``evaluate`` or
 ``serve``): the module ``bench/entries/<entry>.py`` that builds the cell
 from its configuration and mix, warms it, drives the measured window and
 compares what the window produced with the plain reference.  Per-layer
-metrics are read by ``bench/metrics/<metric>.py``, one file each.
+metrics are read by ``bench/metrics/<metric>.py``, one file each, and a
+configuration's scoring model by ``bench/models/<model>.py`` (its tables,
+reference energies and operation counts).
 """
 from __future__ import annotations
 
 import argparse
+import functools
 import gc
 import importlib
 import importlib.util
@@ -38,8 +41,8 @@ def load_json(path: Path) -> dict:
 
 def find(kind: str, name: str, bench: Path = BENCH) -> Path:
     """``bench/<kind>/<name>.json`` (configs, traffic) or ``.py``
-    (metrics, entries): each configuration, mix, metric and entry lives in
-    a file named after it."""
+    (metrics, entries, models): each configuration, mix, metric, entry and
+    scoring model lives in a file named after it."""
     for ext in (".json", ".py"):
         path = bench / kind / f"{name}{ext}"
         if path.exists():
@@ -53,6 +56,14 @@ def load_module(path: Path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+@functools.cache
+def model(name: str):
+    """The scoring model ``bench/models/<name>.py``, loaded once: its
+    ``roles``, ``tables``, ``constrain``, ``energy``, ``candidates``,
+    ``relations``, ``answer_scale`` and operation counts."""
+    return load_module(find("models", name))
 
 
 class CompileClock:

@@ -7,20 +7,29 @@ takes a ``prec``: ``"f32"`` is the reference (float32, matmuls at
 ``highest``); the controls are ``"bf16"`` (tables and arithmetic in
 bfloat16) and ``"high"`` (float32 with three-pass matmuls).
 
+A scoring model's math is its own file, ``bench/models/<model>.py``
+(found by ``harness.model``): its energy, the energies of every candidate
+entity or relation of a query, the constraint training projects onto at
+the start of an epoch, the roles of its tables and a served answer's
+scale.  Tables travel as a dict of any names, each with its leading axis
+indexed by entity (role ``"ent"``) or by relation (role ``"rel"``) and
+any trailing shape.
+
 What the reference restates of the system's published semantics, so that
 its results can be compared one by one:
 
-* energies: TransE ``||h + r - t||_1``, DistMult ``-sum(h * r * t)``;
+* energies: the model file's (TransE ``||h + r - t||_1``, DistMult
+  ``-sum(h * r * t)``);
 * filtered ranking: a known candidate other than the gold entity that
   scores strictly better does not count against the gold;
 * training: each of ``W`` workers holds ``N // W`` triples of a seeded
-  shuffle, projects entity rows to unit length at the start of an epoch,
-  then takes ``N_w // B`` SGD steps on the mean margin loss of a batch and
-  its negatives; the Reduce averages each row over the workers that
-  touched it (by touch count), and the epoch loss is the mean over
-  workers of the mean step loss.  Batches, corruptions and the split are
-  drawn by the documented key scheme: keys fold in (epoch, worker) off
-  ``fold_in(PRNGKey(seed), 0xD417A)``.
+  shuffle, applies the model's constraint at the start of an epoch, then
+  takes ``N_w // B`` SGD steps on the mean margin loss of a batch and its
+  negatives; the Reduce averages each row of every table over the workers
+  that touched it (by the touch count of the table's role), and the epoch
+  loss is the mean over workers of the mean step loss.  Batches,
+  corruptions and the split are drawn by the documented key scheme: keys
+  fold in (epoch, worker) off ``fold_in(PRNGKey(seed), 0xD417A)``.
 """
 from __future__ import annotations
 
@@ -29,6 +38,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import harness
 
 PRECISIONS = {
     "f32": (jnp.float32, "highest"),
@@ -42,7 +53,7 @@ def cast(tables: dict, prec: str) -> dict:
     return {k: jnp.asarray(v).astype(dtype) for k, v in tables.items()}
 
 
-def _dot(a, b, prec):
+def dot(a, b, prec):
     """``a @ b``: at ``highest`` for the reference and the bfloat16
     control, and for ``high`` the three bfloat16 passes spelled out
     (``hi*hi + hi*lo + lo*hi``, products exact in float32), so the
@@ -62,36 +73,9 @@ def _dot(a, b, prec):
     return mm(ah, bh) + mm(ah, bl) + mm(al, bh)
 
 
-# -- energies -----------------------------------------------------------------
-
-def energy(model: str, ent, rel, trip, prec: str = "f32"):
-    h, r, t = ent[trip[..., 0]], rel[trip[..., 1]], ent[trip[..., 2]]
-    if model == "transe":
-        return jnp.sum(jnp.abs(h + r - t), axis=-1)
-    return -jnp.sum(h * r * t, axis=-1)
-
-
-def candidates(model: str, ent, rel, q, side: str, prec: str = "f32"):
-    """Energy of every entity put in ``side`` of each row of ``q``:
-    ``(B, E)``."""
-    r = rel[q[:, 1]]
-    if model == "transe":
-        if side == "tail":
-            x = ent[q[:, 0]] + r
-            return jnp.sum(jnp.abs(x[:, None, :] - ent[None]), axis=-1)
-        x = ent[q[:, 2]] - r
-        return jnp.sum(jnp.abs(ent[None] - x[:, None, :]), axis=-1)
-    fixed = ent[q[:, 0]] if side == "tail" else ent[q[:, 2]]
-    return -_dot(fixed * r, ent.T, prec)
-
-
-def relations(model: str, ent, rel, q, prec: str = "f32"):
-    """Energy of every relation between the head and tail of each row:
-    ``(B, R)``."""
-    h, t = ent[q[:, 0]], ent[q[:, 2]]
-    if model == "transe":
-        return jnp.sum(jnp.abs((h - t)[:, None, :] + rel[None]), axis=-1)
-    return -_dot(h * t, rel.T, prec)
+def unit_rows(x):
+    """Rows scaled to unit L2 length: the constraint projection."""
+    return x / (jnp.sqrt(jnp.sum(x * x, axis=-1, keepdims=True)) + 1e-12)
 
 
 # -- known groups (filtering) -------------------------------------------------
@@ -140,10 +124,11 @@ class Known:
 # -- evaluation ---------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("model", "prec"))
-def _rank_block(ent, rel, q, known_t, known_h, *, model, prec):
+def _rank_block(t, q, known_t, known_h, *, model, prec):
+    m = harness.model(model)
     out = {}
     for side, col, known in (("tail", 2, known_t), ("head", 0, known_h)):
-        s = candidates(model, ent, rel, q, side, prec)
+        s = m.candidates(t, q, side, prec)
         gold_id = q[:, col]
         gold = jnp.take_along_axis(s, gold_id[:, None], axis=1)
         better = s < gold
@@ -151,7 +136,7 @@ def _rank_block(ent, rel, q, known_t, known_h, *, model, prec):
         own = jnp.arange(s.shape[1])[None, :] == gold_id[:, None]
         out[f"{side}_raw"] = raw
         out[f"{side}_filtered"] = raw - jnp.sum(better & known & ~own, axis=1)
-    s = relations(model, ent, rel, q, prec)
+    s = m.relations(t, q, prec)
     gold = jnp.take_along_axis(s, q[:, 1:2], axis=1)
     out["relation"] = 1 + jnp.sum(s < gold, axis=1)
     return out
@@ -171,8 +156,8 @@ def ranks(model: str, tables: dict, test: np.ndarray, known: Known,
             q = np.concatenate([q, np.repeat(q[:1], pad, 0)])
         kt = known.mask("tail", q[:, 0], q[:, 1])
         kh = known.mask("head", q[:, 1], q[:, 2])
-        out = _rank_block(t["ent"], t["rel"], jnp.asarray(q), kt, kh,
-                          model=model, prec=prec)
+        out = _rank_block(t, jnp.asarray(q), kt, kh, model=model,
+                          prec=prec)
         parts.append({k: np.asarray(v)[:block - pad] for k, v in out.items()})
     return {k: np.concatenate([p[k] for p in parts]) for k in parts[0]}
 
@@ -204,9 +189,8 @@ def classification_triples(valid, test, n_entities: int) -> np.ndarray:
 def energies(model: str, tables: dict, triples: np.ndarray,
              prec: str = "f32") -> np.ndarray:
     t = cast(tables, prec)
-    fn = jax.jit(functools.partial(energy, model, prec=prec))
-    return np.asarray(fn(t["ent"], t["rel"], jnp.asarray(triples)),
-                      np.float32)
+    fn = jax.jit(functools.partial(harness.model(model).energy, prec=prec))
+    return np.asarray(fn(t, jnp.asarray(triples)), np.float32)
 
 
 def best_threshold(scores: np.ndarray, labels: np.ndarray) -> float:
@@ -256,35 +240,24 @@ def top_k(model: str, tables: dict, kind: str, a: np.ndarray, b: np.ndarray,
           k: int, known: Known | None, prec: str = "f32"):
     """Best-first ids and energies of ``k`` candidates per query, known
     candidates left out where ``known`` is given, with each query's scale
-    ``||q||_2 * max_e ||e||_2`` (DistMult) or ``k``-free 1 (TransE)."""
+    (the model's ``answer_scale``, from the float32 tables)."""
+    m = harness.model(model)
     t = cast(tables, prec)
-    ent, rel = t["ent"], t["rel"]
     zero = np.zeros_like(a)
     if kind == "relations":
         q = np.stack([a, zero, b], 1)
-        s = relations(model, ent, rel, jnp.asarray(q), prec)
-        other = rel
+        s = m.relations(t, jnp.asarray(q), prec)
     else:
         side = "tail" if kind == "tails" else "head"
         q = (np.stack([a, b, zero], 1) if side == "tail"
              else np.stack([zero, b, a], 1))
-        s = candidates(model, ent, rel, jnp.asarray(q), side, prec)
+        s = m.candidates(t, jnp.asarray(q), side, prec)
         if known is not None:
             pair = (a, b) if side == "tail" else (b, a)
             s = jnp.where(known.mask(side, *pair), jnp.inf, s)
-        other = ent
     s = np.asarray(s, np.float64)
     ids = np.argsort(s, axis=1, kind="stable")[:, :k]
-    scale = np.ones(len(a))
-    if model == "distmult":
-        f32 = cast(tables, "f32")
-        if kind == "relations":
-            qv = f32["ent"][a] * f32["ent"][b]
-        else:                       # (h, r) for tails, (t, r) for heads
-            qv = f32["ent"][a] * f32["rel"][b]
-        rows = jnp.linalg.norm(jnp.asarray(other, jnp.float32), axis=1)
-        scale = np.asarray(jnp.linalg.norm(qv, axis=1) * jnp.max(rows))
-    return ids, s, scale
+    return ids, s, m.answer_scale(cast(tables, "f32"), kind, a, b)
 
 
 # -- training -----------------------------------------------------------------
@@ -299,10 +272,6 @@ def split_workers(seed: int, train: np.ndarray, n_workers: int):
     return train[perm[:per * n_workers].reshape(n_workers, per)]
 
 
-def _unit_rows(x):
-    return x / (jnp.sqrt(jnp.sum(x * x, axis=-1, keepdims=True)) + 1e-12)
-
-
 def _corrupt_batch(key, pos, n_entities: int):
     k_side, k_ent = jax.random.split(key)
     n = pos.shape[0]
@@ -313,23 +282,38 @@ def _corrupt_batch(key, pos, n_entities: int):
     return jnp.stack([h, pos[:, 1], t], 1).astype(pos.dtype)
 
 
+def merge(stacked, count):
+    """The Reduce of one table: ``stacked`` is ``(W, N, ...)``, the
+    workers' copies; ``count`` is ``(W, N)``, how often each worker
+    touched each row.  A row is the touch-weighted mean of the workers'
+    rows, broadcast over its trailing axes; a row no worker touched is the
+    plain mean."""
+    w = count.reshape(count.shape + (1,) * (stacked.ndim - 2))
+    total = jnp.sum(w, axis=0)
+    weighted = jnp.sum(stacked.astype(jnp.float32) * w, axis=0)
+    plain = jnp.mean(stacked.astype(jnp.float32), axis=0)
+    return jnp.where(total > 0, weighted / jnp.maximum(total, 1.0),
+                     plain).astype(stacked.dtype)
+
+
 @functools.partial(
     jax.jit, static_argnames=("model", "batch", "margin", "lr", "prec",
                               "fault"))
 def _epoch(tables, parts, keys, epoch, *, model, batch, margin, lr, prec,
            fault):
+    m = harness.model(model)
     k_data, k_neg = keys
     W, n_w, _ = parts.shape
     steps = n_w // batch
-    E, R = tables["ent"].shape[0], tables["rel"].shape[0]
-    start = dict(tables, ent=_unit_rows(tables["ent"]))
-    dtype = tables["ent"].dtype
+    rows = {m.roles[k]: v.shape[0] for k, v in tables.items()}
+    E, R = rows["ent"], rows["rel"]
+    start = m.constrain(tables)
 
     def loss_fn(p, pos, neg):
         if fault == "half_batch":
             pos, neg = pos[: pos.shape[0] // 2], neg[: neg.shape[0] // 2]
-        d_pos = energy(model, p["ent"], p["rel"], pos, prec)
-        d_neg = energy(model, p["ent"], p["rel"], neg, prec)
+        d_pos = m.energy(p, pos, prec)
+        d_neg = m.energy(p, neg, prec)
         return jnp.mean(jnp.maximum(0.0, margin + d_pos - d_neg))
 
     def worker(w):
@@ -342,7 +326,8 @@ def _epoch(tables, parts, keys, epoch, *, model, batch, margin, lr, prec,
 
         def step(p, b):
             loss, g = jax.value_and_grad(loss_fn)(p, *b)
-            p = jax.tree.map(lambda x, gx: (x - lr * gx).astype(dtype), p, g)
+            p = jax.tree.map(lambda x, gx: (x - lr * gx).astype(x.dtype),
+                             p, g)
             return p, loss
 
         p, losses = jax.lax.scan(step, start, (pos, neg))
@@ -355,17 +340,8 @@ def _epoch(tables, parts, keys, epoch, *, model, batch, margin, lr, prec,
     p, loss, e_count, r_count = jax.vmap(worker)(jnp.arange(W))
     if fault == "no_exchange":
         return {k: v[0] for k, v in p.items()}, jnp.mean(loss)
-
-    def average(stacked, count):
-        w = count[..., None]
-        total = jnp.sum(w, axis=0)
-        weighted = jnp.sum(stacked.astype(jnp.float32) * w, axis=0)
-        plain = jnp.mean(stacked.astype(jnp.float32), axis=0)
-        return jnp.where(total > 0, weighted / jnp.maximum(total, 1.0),
-                         plain).astype(dtype)
-
-    merged = {"ent": average(p["ent"], e_count),
-              "rel": average(p["rel"], r_count)}
+    count = {"ent": e_count, "rel": r_count}
+    merged = {k: merge(v, count[m.roles[k]]) for k, v in p.items()}
     return merged, jnp.mean(loss)
 
 
