@@ -62,6 +62,20 @@ from repro.parallel.util import worker_map
 RankMetrics = host_eval.RankMetrics
 
 DEFAULT_CHUNK = 256
+# bytes of entity rows a scan step gathers per filter column: DEFAULT_CHUNK
+# queries of a 400-float float32 row (DGL-KE's FB15k width for TransE)
+CHUNK_ROW_BYTES = DEFAULT_CHUNK * 400 * 4
+
+
+def chunk_rows(params: Params) -> int:
+    """Queries per scan step for ``params``: ``DEFAULT_CHUNK`` up to
+    400-float entity rows, fewer (a multiple of 8) for wider ones, so the
+    filter correction's substituted-triple gathers — chunk x widest known
+    group x row — stay the size they have at TransE's width (RotatE's
+    2,000-float rows: 48 queries)."""
+    ent = params["ent"]
+    row = int(np.prod(ent.shape[1:])) * np.dtype(ent.dtype).itemsize
+    return int(min(DEFAULT_CHUNK, max(8, CHUNK_ROW_BYTES // row // 8 * 8)))
 
 
 # ---------------------------------------------------------------------------
@@ -406,7 +420,7 @@ def entity_ranks_device(
     cand_masks: Optional[Tuple[np.ndarray, np.ndarray]] = None,
     *,
     model: "str | KGModel" = "transe",
-    chunk: int = DEFAULT_CHUNK,
+    chunk: Optional[int] = None,
     n_workers: int = 1,
     backend: str = "vmap",
     mesh=None,
@@ -445,10 +459,18 @@ def entity_ranks_device(
     test = np.asarray(test, np.int32)
     Q = len(test)
     E = params["ent"].shape[0]
+    if chunk is None:
+        chunk = chunk_rows(params)
     # sharded mode keeps every query on every shard (W=1 in the layout):
     # the candidate axis, not the query axis, is what splits W ways
     S, C, Qp = _layout(Q, chunk, 1 if sharded else n_workers)
     W = n_workers
+    if fused:
+        # both sides, one kernel call per worker and scan step
+        scanned, _ = model.fused_scan_cells(params, C, norm)
+        _, per_query = model.fused_scan_cells(params, 1, norm)
+        obs.count("eval.scan_cells", 2 * W * S * scanned)
+        obs.count("eval.scan_useful_cells", 2 * Q * per_query)
 
     if cand_masks is None:
         # pad-only masks: zero filtering work, filtered == raw (dropped
@@ -501,7 +523,7 @@ def entity_inference_device(
     cand_masks: Optional[Tuple[np.ndarray, np.ndarray]] = None,
     *,
     model: "str | KGModel" = "transe",
-    chunk: int = DEFAULT_CHUNK,
+    chunk: Optional[int] = None,
     n_workers: int = 1,
     backend: str = "vmap",
     mesh=None,
@@ -651,7 +673,7 @@ def evaluate_all_device(
     filtered: bool = True,
     model: "str | KGModel" = "transe",
     *,
-    chunk: int = DEFAULT_CHUNK,
+    chunk: Optional[int] = None,
     n_workers: int = 1,
     backend: str = "vmap",
     mesh=None,
@@ -668,7 +690,8 @@ def evaluate_all_device(
     over the query layout — this is the engine the in-training evaluation
     loop (``core/trace.py``) runs at every Reduce boundary.
 
-    ``chunk`` queries are scored per scan step, split over ``n_workers``
+    ``chunk`` queries (default :func:`chunk_rows`) are scored per scan
+    step, split over ``n_workers``
     along the query axis (``backend="vmap"`` on one device,
     ``"shard_map"`` over a real mesh axis — pass ``mesh``).  ``fused``
     forces the Pallas ``rank_topk`` path on or off (default: auto).

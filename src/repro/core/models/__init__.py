@@ -25,6 +25,7 @@ from repro.core.models.base import (  # noqa: F401  (re-exported API)
     pairwise_hinge,
 )
 from repro.core.models.distmult import DistMult
+from repro.core.models.rotate import RotatE
 from repro.core.models.transe import TransE
 from repro.core.models.transh import TransH
 
@@ -58,3 +59,4 @@ def available() -> Tuple[str, ...]:
 register(TransE())
 register(TransH())
 register(DistMult())
+register(RotatE())

@@ -211,6 +211,11 @@ class KGModel:
     def param_roles(self) -> Dict[str, str]:
         return dict(self.roles)
 
+    def width(self, params: Params) -> int:
+        """The configured width ``k`` (``KGConfig.dim``) of ``params``:
+        the entity row's, unless the model stores rows of another width."""
+        return int(params["ent"].shape[1])
+
     # -- eval scoring (generic fallbacks; override with closed forms) ------
 
     def candidate_energies(
@@ -293,6 +298,17 @@ class KGModel:
         raise NotImplementedError(
             f"{self.name!r} sets supports_fused_kernel but does not "
             "implement fused_rank_counts")
+
+    def fused_scan_cells(
+        self, params: Params, rows: int, norm: str
+    ) -> tuple[int, int]:
+        """(scanned, useful) (query, entity, column) cells one
+        :meth:`fused_rank_counts` call over ``rows`` queries covers: with
+        the kernel's padding, and the problem's own (host arithmetic on
+        shapes, ``kernels/rank_topk.scan_cells``)."""
+        raise NotImplementedError(
+            f"{self.name!r} sets supports_fused_kernel but does not "
+            "implement fused_scan_cells")
 
     # -- negative sampling --------------------------------------------------
 

@@ -154,3 +154,9 @@ class TransE(base.KGModel):
         return rank_topk.rank_counts(
             q, ent, gold_d, norm=norm, interpret=interpret
         )
+
+    def fused_scan_cells(self, params, rows, norm):
+        from repro.kernels import rank_topk
+
+        E, k = params["ent"].shape
+        return rank_topk.scan_cells(rows, E, k, norm=norm)

@@ -91,7 +91,9 @@ class KnowledgeBase:
 
     @property
     def dim(self) -> int:
-        return int(self.params["ent"].shape[1])
+        """The configured width ``k``, which is not every table's row
+        width (RotatE's entity rows hold 2k floats)."""
+        return self.model.width(self.params)
 
     def fingerprint(self) -> str:
         """Content identity of this artifact: a short sha256 over the model
@@ -265,11 +267,10 @@ class KnowledgeBase:
         key = (n_workers, backend, chunk, id(mesh) if mesh is not None
                else None, table_sharding)
         if key not in self._engines:
-            kw = {} if chunk is None else {"chunk": chunk}
             self._engines[key] = KGQueryEngine(
                 self.model, self.params, norm=self.norm,
                 n_workers=n_workers, backend=backend, mesh=mesh,
-                table_sharding=table_sharding, **kw)
+                chunk=chunk, table_sharding=table_sharding)
         return self._engines[key]
 
     def _exclude(self, a, b, side: str) -> np.ndarray:

@@ -27,9 +27,10 @@ Long runs checkpoint and resume **bit-identically** from inside ``fit``:
     kg.fit(graph, epochs=100, ckpt_dir="ckpt", resume=True)
     # == the unbroken 100-epoch run, parameter-for-parameter
 
-``model`` is any name in ``kg.models()`` (transe / transh / distmult / your
-plugin — see ``repro.core.models``); ``paradigm`` is the paper's 'sgd'
-(local epochs + conflict-resolving Reduce) or 'bgd' (gradient Reduce);
+``model`` is any name in ``kg.models()`` (transe / transh / distmult /
+rotate / your plugin — see ``repro.core.models``); ``paradigm`` is the
+paper's 'sgd' (local epochs + conflict-resolving Reduce) or 'bgd'
+(gradient Reduce);
 ``backend`` is 'vmap' (simulated workers, single device) or 'shard_map'
 (real mesh axis, pass ``mesh=``).
 """

@@ -49,8 +49,6 @@ from repro.core import merge as merge_lib
 from repro.core.models import KGModel, Params, get_model
 from repro.parallel.util import worker_map
 
-DEFAULT_CHUNK = eval_device.DEFAULT_CHUNK
-
 
 @dataclasses.dataclass(frozen=True)
 class QueryResult:
@@ -274,7 +272,7 @@ class KGQueryEngine:
         n_workers: int = 1,
         backend: str = "vmap",
         mesh=None,
-        chunk: int = DEFAULT_CHUNK,
+        chunk: Optional[int] = None,
         table_sharding: str = "replicated",
     ):
         if table_sharding not in ("replicated", "sharded"):
@@ -287,7 +285,8 @@ class KGQueryEngine:
         self.n_workers = n_workers
         self.backend = backend
         self.mesh = mesh
-        self.chunk = chunk
+        # queries per scan step: the eval engine's row-byte rule unless set
+        self.chunk = eval_device.chunk_rows(params) if chunk is None else chunk
         self.table_sharding = table_sharding
         self.n_entities = int(params["ent"].shape[0])
         self.n_relations = int(params["rel"].shape[0])

@@ -27,6 +27,8 @@ from repro.kb import KnowledgeBase
 from repro.serve.kg_engine import KGQueryEngine
 
 MODELS = ["transe", "transh", "distmult"]
+# the per-model scan contracts (slice energies, sharded ranks and top-k)
+CONTRACT_MODELS = MODELS + ["rotate"]
 STRATEGIES = list(merge_lib.STRATEGIES)
 W = 3          # does not divide n_entities=200: ragged shard blocks
 
@@ -159,8 +161,10 @@ def test_sharded_matrix(graph, model, strategy):
 # The per-model slice contract the sharded scan is built on
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("model_name", MODELS)
-@pytest.mark.parametrize("norm", ["l1", "l2"])
+# RotatE has one energy (the sum of complex moduli): l1 only
+@pytest.mark.parametrize(
+    "norm,model_name",
+    [(norm, m) for norm in ("l1", "l2") for m in MODELS] + [("l1", "rotate")])
 def test_candidate_slice_energies_contract(graph, model_name, norm):
     """``candidate_slice_energies`` == columns [lo, lo+n) of the full
     score matrix, **bitwise**, for ragged block offsets — the per-model
@@ -186,7 +190,7 @@ def test_candidate_slice_energies_contract(graph, model_name, norm):
 # Eval: shard-local candidate scan + exact cross-shard combine
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("model_name", MODELS)
+@pytest.mark.parametrize("model_name", CONTRACT_MODELS)
 def test_sharded_eval_ranks_bitwise(graph, masks, model_name):
     """Raw, filtered, and relation ranks from the sharded scan equal the
     replicated scan's exactly — gold via cross-shard min, counts via
@@ -262,7 +266,7 @@ def _assert_query_equal(a, b, label=""):
     np.testing.assert_array_equal(a.energies, b.energies, err_msg=label)
 
 
-@pytest.mark.parametrize("model_name", MODELS)
+@pytest.mark.parametrize("model_name", CONTRACT_MODELS)
 def test_sharded_topk_bitwise(graph, model_name):
     """k < R, k > R (local kk cut), and k = E (full table) — every local
     cut provably keeps each global winner, so the combined top-k matches

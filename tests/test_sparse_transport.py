@@ -34,6 +34,8 @@ from repro.core.models import KGConfig, get_model
 from repro.data import kg as kg_lib
 
 MODELS = ["transe", "transh", "distmult"]
+# the per-model contracts (transport, compact step, row-local projection)
+CONTRACT_MODELS = MODELS + ["rotate"]
 STRATEGIES = list(merge_lib.STRATEGIES)
 
 
@@ -86,7 +88,7 @@ def test_sparse_matches_dense_host(small_kg, strategy):
     _assert_identical(*_pair(small_kg, strategy=strategy))
 
 
-@pytest.mark.parametrize("model", MODELS)
+@pytest.mark.parametrize("model", CONTRACT_MODELS)
 def test_sparse_matches_dense_device(small_kg, model):
     """Device pipeline with deferred Reduces (merge_every=2): K local
     epochs of drift between merges, roles-aware extra tables (TransH's
@@ -228,7 +230,7 @@ def _random_batch(rng, E, R, B):
     ], axis=1).astype(np.int32))
 
 
-@pytest.mark.parametrize("model_name", MODELS)
+@pytest.mark.parametrize("model_name", CONTRACT_MODELS)
 @pytest.mark.parametrize("normalize", ["epoch", "step"])
 def test_compact_step_bitwise(model_name, normalize):
     """``sgd_step_sparse`` == ``sgd_step`` bitwise: same forward floats on
@@ -272,7 +274,7 @@ def _assert_close_tables(compact, dense):
             rtol=TABLE_RTOL, atol=TABLE_ATOL, err_msg=f"table {k}")
 
 
-@pytest.mark.parametrize("model_name", MODELS)
+@pytest.mark.parametrize("model_name", CONTRACT_MODELS)
 def test_compact_step_close_where_candidates_fewer(model_name):
     """``sgd_step_sparse`` against the plain full-table gradient step on
     batches whose 4B candidate slots are fewer than the table's rows (so
@@ -375,7 +377,7 @@ def test_map_step_follows_dispatch_rule(wide_kg, normalize, batch_size,
     obs.reset()
 
 
-@pytest.mark.parametrize("model_name", MODELS)
+@pytest.mark.parametrize("model_name", CONTRACT_MODELS)
 def test_normalize_rows_row_local_contract(model_name):
     """The transport contract: ``normalize(params)[name][ids] ==
     normalize_rows(name, params[name][ids])`` bitwise, per table — the

@@ -83,6 +83,65 @@ def test_default_eval_program_holds_kernel(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# RotatE at the authors' FB15k width: 1,000 complex columns, so entity rows
+# of 2,000 floats; its eval chunk (48 rows, ``eval_device.chunk_rows``)
+# split over W in {1, 4} workers
+ROTATE_K = 1000
+
+
+@pytest.mark.parametrize("rows", [12, 48])
+def test_modulus_rank_counts_compiles(one_chip, rows):
+    E = smoke.N_ENTITIES
+    fn = jax.jit(lambda q, t, g: rank_topk.modulus_rank_counts(
+        q, t, g, interpret=False))
+    compiled = fn.lower(_spec(one_chip, (rows, 2 * ROTATE_K)),
+                        _spec(one_chip, (E, 2 * ROTATE_K)),
+                        _spec(one_chip, (rows,))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_kernels_carry_their_own_names(one_chip):
+    """TransE's kernel is still the custom call ``rank_counts`` and
+    RotatE's is ``modulus_rank_counts``: the trace finds each by name."""
+    E, k = smoke.N_ENTITIES, smoke.FIT["dim"]
+    transe = jax.jit(lambda q, t, g: rank_topk.rank_counts(q, t, g)).lower(
+        _spec(one_chip, (64, k)), _spec(one_chip, (E, k)),
+        _spec(one_chip, (64,))).compile().as_text()
+    rotate = jax.jit(
+        lambda q, t, g: rank_topk.modulus_rank_counts(q, t, g)).lower(
+        _spec(one_chip, (48, 2 * ROTATE_K)),
+        _spec(one_chip, (E, 2 * ROTATE_K)),
+        _spec(one_chip, (48,))).compile().as_text()
+    assert re.search(r"%rank_counts[.\d]* = .*tpu_custom_call", transe)
+    assert not re.search(r"%modulus_rank_counts", transe)
+    assert re.search(r"%modulus_rank_counts[.\d]* = .*tpu_custom_call",
+                     rotate)
+
+
+def test_rotate_eval_program_fits_one_chip(one_chip):
+    """RotatE's default device eval at FB15k's shape — its 48-query chunk,
+    the fused kernel, the filter over known groups 1,001 wide and the
+    relation scan — compiles with the kernel and needs less than one
+    chip's memory."""
+    E, R = smoke.N_ENTITIES, smoke.N_RELATIONS
+    tables = {"ent": _spec(one_chip, (E, 2 * ROTATE_K)),
+              "rel": _spec(one_chip, (R, ROTATE_K))}
+    C = eval_device.chunk_rows(tables)
+    assert C == 48
+    S, C, _ = eval_device._layout(59_071, C, 1)
+    ids = _spec(one_chip, (1, S, C, 3), jnp.int32)
+    cands = _spec(one_chip, (1, S, C, 1001), jnp.int32)
+    compiled = eval_device._entity_ranks_device.lower(
+        get_model("rotate"), tables, ids, cands, cands, norm="l1",
+        backend="vmap", mesh=None, axis_name="workers", fused=True,
+        relations=True).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 0 < total < HBM_BYTES, mem
+
+
 def _training_block(sharding, **fit_kw):
     """The smoke's one-epoch device-pipeline block, compiled, and its
     worker count."""
